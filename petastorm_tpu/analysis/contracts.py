@@ -52,13 +52,26 @@ telemetry back).
 #: entry fetched from a peer worker's serve socket instead of decoded
 #: locally: request + streamed Arrow IPC bytes + verify + atomic
 #: publish into the local disk tier (service/peer_cache.py; wire-priced
-#: where ``decode`` would be decode-priced)
+#: where ``decode`` would be decode-priced) · ``loader_next`` the
+#: consumer blocked in ``JaxLoader.__next__`` on the staged-batch queue ·
+#: ``stage_backpressure`` the staging thread blocked handing a staged
+#: batch to a full queue · ``serialize`` a process-pool worker's pickle-5
+#: framing and send of one result (its wait for room in the channel is
+#: producer wait, not this) · ``deserialize`` the consumer's receive and
+#: pickle-5 rebuild of one process-pool message
 STAGES = ('ventilate', 'io', 'decode', 'filter', 'transform', 'queue_wait',
           'collate', 'h2d', 'h2d_ready', 'stage_fill', 'h2d_dispatch',
           'cache_hit_read', 'cache_fill', 'decode_fused',
           'rowgroup_prune', 'late_materialize', 'autotune',
           'readahead_fetch', 'pack', 'encode', 'write_flush', 'compact',
-          'peer_fetch')
+          'peer_fetch', 'loader_next', 'stage_backpressure', 'serialize',
+          'deserialize')
+
+#: the stages that wait rather than work: their seconds leave out any span
+#: nested inside them (telemetry/spans.py), and a device idle gap under one
+#: of them is not the loader's work (docs/telemetry.md, timeline)
+WAIT_STAGES = frozenset(['queue_wait', 'loader_next', 'stage_backpressure',
+                         'h2d_ready'])
 
 #: every trace-event name the package records outside the canonical stage
 #: spans (docs/telemetry.md, tracing section)
@@ -103,8 +116,11 @@ EVENT_NAMES = frozenset([
 METRIC_NAMES = frozenset([
     # stage spans (telemetry/spans.py)
     'petastorm_tpu_stage_seconds_total',
+    'petastorm_tpu_stage_cpu_seconds_total',
     'petastorm_tpu_stage_calls_total',
     'petastorm_tpu_stage_duration_seconds',
+    # process CPU by role (telemetry/registry.py)
+    'petastorm_tpu_process_cpu_seconds_total',
     # stall wait clocks (telemetry/__init__.py)
     'petastorm_tpu_stall_producer_wait_seconds_total',
     'petastorm_tpu_stall_consumer_wait_seconds_total',

@@ -27,7 +27,8 @@ RULES = (RULE, RULE_FAULTPOINT)
 
 #: calls recording a stage span or trace event; first arg ∈ STAGES ∪
 #: EVENT_NAMES (spans share names with the trace timeline's tracks)
-_RECORDING_CALLS = frozenset(['span', 'record_complete', 'record_instant'])
+_RECORDING_CALLS = frozenset(['span', 'timed', 'record_complete',
+                              'record_instant'])
 
 #: registry metric constructors/readers; first arg ∈ METRIC_NAMES
 _METRIC_CALLS = frozenset(['counter', 'gauge', 'histogram',

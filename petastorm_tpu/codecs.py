@@ -33,6 +33,7 @@ import pyarrow as pa
 
 from petastorm_tpu import faults
 from petastorm_tpu.telemetry import knobs
+from petastorm_tpu.telemetry.spans import add_cpu
 from petastorm_tpu.unischema import numpy_to_arrow_type
 
 logger = logging.getLogger(__name__)
@@ -189,15 +190,22 @@ def _jpeg_upsampling_mode(decode_fn, cells, image_shape):
         return _JPEG_FANCY_MODE
 
 
+def _pool_cpu_out():
+    """The one-int64 buffer a native batch decoder adds its pool threads'
+    CPU nanoseconds to (its ``cpu_out`` argument)."""
+    return np.zeros(1, np.int64)
+
+
 def _native_supports_threads(decode_fn, out, prefix_args):
     """True when this native build's batch decoder accepts the trailing
-    ``threads`` argument (probed once per function with a zero-length
-    call; a stale ``.so`` predating the argument raises TypeError and is
-    routed to the Python-side chunking fallback)."""
+    ``threads`` and ``cpu_out`` arguments (probed once per function with a
+    zero-length call; a stale ``.so`` predating them raises TypeError and
+    is routed to the Python-side chunking fallback)."""
     ok = _NATIVE_THREADS_SUPPORT.get(decode_fn)
     if ok is None:
         try:
-            decode_fn([], out[:0], *(tuple(prefix_args) + (1,)))
+            decode_fn([], out[:0],
+                      *(tuple(prefix_args) + (1, _pool_cpu_out())))
             ok = True
         except TypeError:
             ok = False
@@ -683,8 +691,10 @@ class CompressedImageCodec(DataframeColumnCodec):
             # ONE native call: the C pool fans the whole row-group column
             # out with the GIL released. The Python executor is NOT also
             # engaged (nor created) — the knob sizes exactly one pool per
-            # batch.
-            run(0, n, threaded_prefix + (threads,))
+            # batch. The pool's CPU goes to the enclosing stage span.
+            pool_cpu = _pool_cpu_out()
+            run(0, n, threaded_prefix + (threads, pool_cpu))
+            add_cpu(int(pool_cpu[0]) / 1e9)
             return True
         # only the chunked fallback (stale .so / serial knob) consults
         # the Python-side executor into existence
@@ -764,12 +774,14 @@ class NdarrayCodec(DataframeColumnCodec):
         # fall back to the Python path, which preserves the true shape.
         shape_str = "'shape': %r" % (tuple(int(d) for d in shape),)
         threads = image_decoder_threads()
+        pool_cpu = _pool_cpu_out()
         try:
             done = native.decode_npy_batch(cells, dense, dtype.str,
-                                           shape_str, threads)
+                                           shape_str, threads, pool_cpu)
         except TypeError:  # stale .so predating the threads argument
             done = native.decode_npy_batch(cells, dense, dtype.str,
                                            shape_str)
+        add_cpu(int(pool_cpu[0]) / 1e9)
         if done == len(cells):
             # Return the contiguous batch itself: downstream collation
             # (arrow_worker._stack) passes it through, avoiding a second

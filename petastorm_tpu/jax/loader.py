@@ -33,6 +33,7 @@ from petastorm_tpu.telemetry import (
     STALL_NOTE_FLOOR_S, StallAttributor, note_consumer_wait,
     note_producer_wait, span, tracing,
 )
+from petastorm_tpu.telemetry.spans import timed
 
 logger = logging.getLogger(__name__)
 
@@ -306,7 +307,8 @@ class JaxLoader:
         # events (collate/h2d) attribute to the pull being folded in —
         # the honest approximation for a batching stage
         self._last_pull_ctx = None
-        # staging gauges (see diagnostics): who is waiting on whom?
+        # staging gauges (see diagnostics): who is waiting on whom? The
+        # seconds of this loader's loader_next and stage_backpressure spans
         self._consumer_wait_s = 0.0   # consumer blocked on get → input-bound
         self._stage_blocked_s = 0.0   # producer blocked on put → compute-bound
         # loader-local stall attributor: the same two clocks, bucketed into
@@ -317,6 +319,9 @@ class JaxLoader:
         self._attributor = StallAttributor()
         self._awaiting_first_delivery = True
         self._batches_delivered = 0
+        # batches handed to the queue; equal to the delivered count at
+        # delivery (FIFO), so it numbers the batch the stager works on
+        self._batches_staged = 0
         # registry snapshot taken at each pass's first delivery: scopes
         # autotune's decoded-cache verdict to the CURRENT pass (lifetime
         # counters would blend epoch 1's full decode cost into every
@@ -474,7 +479,8 @@ class JaxLoader:
                 self._autotuner.apply_learned(self._stager)
         self._out_queue = queue.Queue(maxsize=self._prefetch)
         self._stage_thread = threading.Thread(target=self._stage_loop,
-                                              daemon=True)
+                                              daemon=True,
+                                              name='petastorm-tpu-stager')
         self._stage_thread.start()
         return self
 
@@ -483,6 +489,28 @@ class JaxLoader:
             iter(self)
         if self._exhausted:
             raise StopIteration
+        tracing.tag_batch(self._batches_delivered)
+        waiting = timed('loader_next')
+        try:
+            with waiting:
+                batch, pull_counts = self._next_item()
+        finally:
+            self._consumer_wait_s += waiting.seconds
+        if pull_counts:
+            self._record_delivery(pull_counts)
+        self._batches_delivered += 1
+        if self._awaiting_first_delivery:
+            # spin-up over: drop the startup waits so autotune's
+            # attribution covers steady state of the current pass only
+            self._attributor.reset()
+            self._awaiting_first_delivery = False
+            from petastorm_tpu.telemetry import get_registry
+            self._pass_baseline = get_registry().snapshot()
+        return batch
+
+    def _next_item(self):
+        """The next staged ``(batch, pull_counts)``, leftovers first;
+        raises StopIteration at the pass's end, or the staging error."""
         while True:
             with self._drain_lock:
                 item = (self._leftovers.pop(0) if self._leftovers
@@ -494,9 +522,8 @@ class JaxLoader:
                         item = self._out_queue.get(timeout=0.1)
                     finally:
                         waited = time.monotonic() - t0
-                        self._consumer_wait_s += waited
                         if waited > STALL_NOTE_FLOOR_S:
-                            # queue_wait is a canonical stage AND
+                            # the wait is loader_next's seconds AND
                             # producer-bound evidence (both the loader's
                             # own attributor and the process-wide one)
                             self._attributor.note_consumer_wait(waited)
@@ -526,18 +553,7 @@ class JaxLoader:
                 if self._stage_error is not None:
                     raise self._stage_error
                 raise StopIteration
-            batch, pull_counts = item
-            if pull_counts:
-                self._record_delivery(pull_counts)
-            self._batches_delivered += 1
-            if self._awaiting_first_delivery:
-                # spin-up over: drop the startup waits so autotune's
-                # attribution covers steady state of the current pass only
-                self._attributor.reset()
-                self._awaiting_first_delivery = False
-                from petastorm_tpu.telemetry import get_registry
-                self._pass_baseline = get_registry().snapshot()
-            return batch
+            return item
 
     def _record_delivery(self, pull_counts):
         """Credit delivered rows to their pulls; a pull whose every row has
@@ -655,6 +671,7 @@ class JaxLoader:
             yield columns
 
     def _stage_loop(self):
+        tracing.tag_batch(self._batches_staged)
         try:
             if self._bucket_field is not None:
                 self._stage_loop_bucketed()
@@ -931,6 +948,8 @@ class JaxLoader:
         # provenance rides the queue as a sidecar: rows count as delivered
         # only when the consumer actually receives this item in __next__
         self._put_blocking((device_batch, pull_counts))
+        self._batches_staged += 1
+        tracing.tag_batch(self._batches_staged)
         if self._autotuner is not None:
             # staging-thread cadence gate: a no-op monotonic compare
             # until the next autotune window is due
@@ -1122,18 +1141,19 @@ class JaxLoader:
         return depth
 
     def _put_blocking(self, item):
-        start = time.monotonic()
+        waiting = timed('stage_backpressure')
         try:
-            while not self._stop_event.is_set():
-                try:
-                    self._out_queue.put(item, timeout=0.1)
-                    return
-                except queue.Full:
-                    continue
+            with waiting:
+                while not self._stop_event.is_set():
+                    try:
+                        self._out_queue.put(item, timeout=0.1)
+                        return
+                    except queue.Full:
+                        continue
         finally:
             # time the producer spent blocked on a full queue: back-pressure
             # from a consumer that is NOT input-bound
-            blocked = time.monotonic() - start
+            blocked = waiting.seconds
             self._stage_blocked_s += blocked
             if blocked > STALL_NOTE_FLOOR_S:
                 self._attributor.note_producer_wait(blocked)

@@ -165,7 +165,7 @@ class _OrderedDocSource:
             self._pull()
 
     def _pull(self):
-        t0 = time.time()
+        t0 = time.perf_counter_ns()
         try:
             columns, item, epoch = self._reader.next_batch_info()
         except StopIteration:
@@ -179,7 +179,7 @@ class _OrderedDocSource:
             ctx = tracing.ctx_for(item, epoch, shard=self._source)
             if ctx is not None:
                 tracing.record_complete(
-                    'mixture_pull', t0, time.time() - t0, ctx,
+                    'mixture_pull', t0, time.perf_counter_ns(), ctx,
                     track='mixture-src-%d' % self._source)
         column = columns.get(self._token_field)
         if column is None:

@@ -49,11 +49,50 @@
 #include <stdlib.h>
 #include <string.h>
 #include <strings.h>  /* strcasecmp: POSIX, not ISO string.h */
+#include <time.h>
 #include <jpeglib.h>
 
 /* clamp on the internal decode pool: beyond this, thread-spawn cost and
  * memory-bandwidth contention dominate any decode parallelism win */
 #define PT_MAX_THREADS 32
+
+/* CPU the calling thread has used so far, in ns. A pool thread reads it
+ * just before it returns: the pool's CPU, which the caller's own thread
+ * clock cannot see. */
+static long long
+pt_thread_cpu_ns(void)
+{
+    struct timespec ts;
+    if (clock_gettime(CLOCK_THREAD_CPUTIME_ID, &ts) != 0)
+        return 0;
+    return (long long)ts.tv_sec * 1000000000LL + (long long)ts.tv_nsec;
+}
+
+/* Add ns to the int64 held in the first 8 bytes of cpu_out, a writable
+ * buffer the caller passes to learn the pool threads' CPU (the calling
+ * thread's own CPU is not in it); NULL/None is ignored. Needs the GIL. */
+static int
+pt_add_cpu_out(PyObject *cpu_out, long long ns)
+{
+    Py_buffer view;
+    long long total;
+
+    if (cpu_out == NULL || cpu_out == Py_None)
+        return 0;
+    if (PyObject_GetBuffer(cpu_out, &view, PyBUF_WRITABLE) != 0)
+        return -1;
+    if (view.len < (Py_ssize_t)sizeof(total)) {
+        PyBuffer_Release(&view);
+        PyErr_SetString(PyExc_ValueError,
+                        "cpu_out must be a writable buffer of one int64");
+        return -1;
+    }
+    memcpy(&total, view.buf, sizeof(total));
+    total += ns;
+    memcpy(view.buf, &total, sizeof(total));
+    PyBuffer_Release(&view);
+    return 0;
+}
 
 struct pt_jpeg_error_mgr {
     struct jpeg_error_mgr pub;
@@ -144,6 +183,7 @@ struct pt_jpeg_task {
     int height, width;
     boolean fancy;
     J_DCT_METHOD dct;
+    long long cpu_ns;  /* a pool thread's CPU, read as it returns */
 };
 
 static void *
@@ -186,22 +226,31 @@ pt_jpeg_worker(void *arg)
     return NULL;
 }
 
+static void *
+pt_jpeg_thread(void *arg)
+{
+    pt_jpeg_worker(arg);
+    ((struct pt_jpeg_task *)arg)->cpu_ns = pt_thread_cpu_ns();
+    return NULL;
+}
+
 /* Fan n_views cells across `threads` pool threads (the calling thread is
  * worker 0) and fold the per-range failures into the batch-wide decoded
  * prefix: the first rejected index overall. Cells after that index in
  * OTHER ranges were decoded too — harmless, the caller may re-dispatch
- * them — but the prefix contract only promises the leading run. Runs
- * with the GIL released; workers never touch the Python API. */
+ * them — but the prefix contract only promises the leading run. Adds the
+ * pool threads' CPU to *pool_cpu_ns. Runs with the GIL released; workers
+ * never touch the Python API. */
 static Py_ssize_t
 pt_jpeg_run(struct pt_jpeg_task *tasks, Py_ssize_t n_tasks,
-            Py_ssize_t n_views)
+            Py_ssize_t n_views, long long *pool_cpu_ns)
 {
     pthread_t tids[PT_MAX_THREADS];
     int created[PT_MAX_THREADS] = {0};
     Py_ssize_t t, decoded;
 
     for (t = 1; t < n_tasks; t++) {
-        if (pthread_create(&tids[t], NULL, pt_jpeg_worker,
+        if (pthread_create(&tids[t], NULL, pt_jpeg_thread,
                            &tasks[t]) != 0) {
             /* spawn failure: this range decodes 0 cells (fail == lo,
              * pre-set by the dispatcher) and the prefix fold below
@@ -213,8 +262,10 @@ pt_jpeg_run(struct pt_jpeg_task *tasks, Py_ssize_t n_tasks,
     }
     pt_jpeg_worker(&tasks[0]);
     for (t = 1; t < n_tasks; t++) {
-        if (created[t])
+        if (created[t]) {
             pthread_join(tids[t], NULL);
+            *pool_cpu_ns += tasks[t].cpu_ns;
+        }
     }
     decoded = n_views;
     for (t = 0; t < n_tasks; t++) {
@@ -235,10 +286,14 @@ decode_jpeg_batch(PyObject *self, PyObject *args)
     int height, width;
     int fancy_arg = -1;
     int threads_arg = 0;
+    PyObject *cpu_out = NULL;
+    long long pool_cpu_ns = 0;
 
     (void)self;
-    if (!PyArg_ParseTuple(args, "OO|ii", &cells, &out_obj, &fancy_arg,
-                          &threads_arg))
+    if (!PyArg_ParseTuple(args, "OO|iiO", &cells, &out_obj, &fancy_arg,
+                          &threads_arg, &cpu_out))
+        return NULL;
+    if (pt_add_cpu_out(cpu_out, 0) != 0)
         return NULL;
     /* C-contiguous + ND so shape[] is populated (a plain "w*" request
      * yields a 1-D view with no shape information) */
@@ -339,7 +394,7 @@ decode_jpeg_batch(PyObject *self, PyObject *args)
             tasks[t].dct = dct;
         }
         Py_BEGIN_ALLOW_THREADS
-        decoded = pt_jpeg_run(tasks, n_tasks, n_views);
+        decoded = pt_jpeg_run(tasks, n_tasks, n_views, &pool_cpu_ns);
         Py_END_ALLOW_THREADS
 
         for (i = 0; i < n_views; i++)
@@ -347,17 +402,20 @@ decode_jpeg_batch(PyObject *self, PyObject *args)
     }
     PyMem_Free(views);
     PyBuffer_Release(&out_view);
+    if (pt_add_cpu_out(cpu_out, pool_cpu_ns) != 0)
+        return NULL;
     return PyLong_FromSsize_t(decoded);
 }
 
 static PyMethodDef jpeg_batch_methods[] = {
     {"decode_jpeg_batch", decode_jpeg_batch, METH_VARARGS,
-     "decode_jpeg_batch(cells, out, fancy=-1, threads=0): batched RGB "
-     "JPEG decode into a preallocated (N,H,W,3) uint8 array; returns the "
-     "decoded prefix count. fancy: 1 = fancy upsampling "
+     "decode_jpeg_batch(cells, out, fancy=-1, threads=0, cpu_out=None): "
+     "batched RGB JPEG decode into a preallocated (N,H,W,3) uint8 array; "
+     "returns the decoded prefix count. fancy: 1 = fancy upsampling "
      "(cv2-bit-identical), 0 = merged, -1 = PETASTORM_TPU_JPEG_FANCY env "
      "default. threads > 1 fans the cells across an internal pthread "
-     "pool (GIL released; one decompress object per thread)"},
+     "pool (GIL released; one decompress object per thread); cpu_out, a "
+     "writable int64 buffer, gains the pool threads' CPU ns"},
     {NULL, NULL, 0, NULL}
 };
 

@@ -20,9 +20,48 @@
 #include <pthread.h>
 #include <string.h>
 #include <stdint.h>
+#include <time.h>
 
 /* same internal-pool clamp as jpeg_batch.c / png_batch.c */
 #define PT_MAX_THREADS 32
+
+/* CPU the calling thread has used so far, in ns. A pool thread reads it
+ * just before it returns: the pool's CPU, which the caller's own thread
+ * clock cannot see. */
+static long long
+pt_thread_cpu_ns(void)
+{
+    struct timespec ts;
+    if (clock_gettime(CLOCK_THREAD_CPUTIME_ID, &ts) != 0)
+        return 0;
+    return (long long)ts.tv_sec * 1000000000LL + (long long)ts.tv_nsec;
+}
+
+/* Add ns to the int64 held in the first 8 bytes of cpu_out, a writable
+ * buffer the caller passes to learn the pool threads' CPU (the calling
+ * thread's own CPU is not in it); NULL/None is ignored. Needs the GIL. */
+static int
+pt_add_cpu_out(PyObject *cpu_out, long long ns)
+{
+    Py_buffer view;
+    long long total;
+
+    if (cpu_out == NULL || cpu_out == Py_None)
+        return 0;
+    if (PyObject_GetBuffer(cpu_out, &view, PyBUF_WRITABLE) != 0)
+        return -1;
+    if (view.len < (Py_ssize_t)sizeof(total)) {
+        PyBuffer_Release(&view);
+        PyErr_SetString(PyExc_ValueError,
+                        "cpu_out must be a writable buffer of one int64");
+        return -1;
+    }
+    memcpy(&total, view.buf, sizeof(total));
+    total += ns;
+    memcpy(view.buf, &total, sizeof(total));
+    PyBuffer_Release(&view);
+    return 0;
+}
 
 static const char NPY_MAGIC[6] = {'\x93', 'N', 'U', 'M', 'P', 'Y'};
 
@@ -108,6 +147,7 @@ struct pt_npy_task {
     char *out_data;
     Py_ssize_t row_bytes;
     Py_ssize_t lo, hi;
+    long long cpu_ns;  /* a pool thread's CPU, read as it returns */
 };
 
 static void *
@@ -122,11 +162,20 @@ pt_npy_worker(void *arg)
     return NULL;
 }
 
+static void *
+pt_npy_thread(void *arg)
+{
+    pt_npy_worker(arg);
+    ((struct pt_npy_task *)arg)->cpu_ns = pt_thread_cpu_ns();
+    return NULL;
+}
+
 /* decode_npy_batch(cells: sequence of bytes-like or None,
  *                  out: ndarray (n, ...) C-contiguous, writable,
  *                  descr: str like '<f4',
  *                  shape_str: str like "'shape': (2, 3)",
- *                  threads: int = 0)
+ *                  threads: int = 0,
+ *                  cpu_out: writable int64 buffer or None = None)
  * Returns: number of successfully decoded leading cells. A cell that is
  * None or incompatible stops fast-path decoding at its index (caller
  * finishes those via the Python path).
@@ -147,11 +196,15 @@ decode_npy_batch(PyObject *self, PyObject *args)
     Py_ssize_t row_bytes;
     char *out_data;
     int threads_arg = 0;
+    PyObject *cpu_out = NULL;
+    long long pool_cpu_ns = 0;
     Py_buffer *views = NULL;
     const char **srcs = NULL;
 
-    if (!PyArg_ParseTuple(args, "OO!ss|i", &cells, &PyArray_Type, &out,
-                          &descr, &shape_str, &threads_arg))
+    if (!PyArg_ParseTuple(args, "OO!ss|iO", &cells, &PyArray_Type, &out,
+                          &descr, &shape_str, &threads_arg, &cpu_out))
+        return NULL;
+    if (pt_add_cpu_out(cpu_out, 0) != 0)
         return NULL;
     if (!PyArray_IS_C_CONTIGUOUS(out) || !PyArray_ISWRITEABLE(out)) {
         PyErr_SetString(PyExc_ValueError,
@@ -237,13 +290,14 @@ decode_npy_batch(PyObject *self, PyObject *args)
             pthread_t tids[PT_MAX_THREADS];
             int created[PT_MAX_THREADS] = {0};
             for (t = 1; t < n_tasks; t++)
-                created[t] = pthread_create(&tids[t], NULL, pt_npy_worker,
+                created[t] = pthread_create(&tids[t], NULL, pt_npy_thread,
                                             &tasks[t]) == 0;
             pt_npy_worker(&tasks[0]);
             for (t = 1; t < n_tasks; t++) {
-                if (created[t])
+                if (created[t]) {
                     pthread_join(tids[t], NULL);
-                else
+                    pool_cpu_ns += tasks[t].cpu_ns;
+                } else
                     pt_npy_worker(&tasks[t]);  /* spawn failed: copy inline */
             }
         }
@@ -254,15 +308,18 @@ decode_npy_batch(PyObject *self, PyObject *args)
         PyBuffer_Release(&views[i]);
     PyMem_Free(views);
     PyMem_Free(srcs);
+    if (pt_add_cpu_out(cpu_out, pool_cpu_ns) != 0)
+        return NULL;
     return PyLong_FromSsize_t(n_ok);
 }
 
 static PyMethodDef NpyBatchMethods[] = {
     {"decode_npy_batch", decode_npy_batch, METH_VARARGS,
-     "decode_npy_batch(cells, out, descr, shape_str, threads=0): batched "
-     ".npy decode into a preallocated array; returns the decoded prefix "
-     "count. Payload memcpys run with the GIL released, fanned across an "
-     "internal pthread pool when threads > 1"},
+     "decode_npy_batch(cells, out, descr, shape_str, threads=0, "
+     "cpu_out=None): batched .npy decode into a preallocated array; "
+     "returns the decoded prefix count. Payload memcpys run with the GIL "
+     "released, fanned across an internal pthread pool when threads > 1; "
+     "cpu_out, a writable int64 buffer, gains the pool threads' CPU ns"},
     {NULL, NULL, 0, NULL}
 };
 

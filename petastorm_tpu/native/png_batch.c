@@ -30,10 +30,49 @@
 #include <setjmp.h>
 #include <stddef.h>
 #include <string.h>
+#include <time.h>
 #include <png.h>
 
 /* same internal-pool clamp as jpeg_batch.c */
 #define PT_MAX_THREADS 32
+
+/* CPU the calling thread has used so far, in ns. A pool thread reads it
+ * just before it returns: the pool's CPU, which the caller's own thread
+ * clock cannot see. */
+static long long
+pt_thread_cpu_ns(void)
+{
+    struct timespec ts;
+    if (clock_gettime(CLOCK_THREAD_CPUTIME_ID, &ts) != 0)
+        return 0;
+    return (long long)ts.tv_sec * 1000000000LL + (long long)ts.tv_nsec;
+}
+
+/* Add ns to the int64 held in the first 8 bytes of cpu_out, a writable
+ * buffer the caller passes to learn the pool threads' CPU (the calling
+ * thread's own CPU is not in it); NULL/None is ignored. Needs the GIL. */
+static int
+pt_add_cpu_out(PyObject *cpu_out, long long ns)
+{
+    Py_buffer view;
+    long long total;
+
+    if (cpu_out == NULL || cpu_out == Py_None)
+        return 0;
+    if (PyObject_GetBuffer(cpu_out, &view, PyBUF_WRITABLE) != 0)
+        return -1;
+    if (view.len < (Py_ssize_t)sizeof(total)) {
+        PyBuffer_Release(&view);
+        PyErr_SetString(PyExc_ValueError,
+                        "cpu_out must be a writable buffer of one int64");
+        return -1;
+    }
+    memcpy(&total, view.buf, sizeof(total));
+    total += ns;
+    memcpy(view.buf, &total, sizeof(total));
+    PyBuffer_Release(&view);
+    return 0;
+}
 
 struct pt_mem_reader {
     const unsigned char *data;
@@ -121,6 +160,7 @@ struct pt_png_task {
     Py_ssize_t lo, hi;
     Py_ssize_t fail;
     int height, width;
+    long long cpu_ns;  /* a pool thread's CPU, read as it returns */
 };
 
 static void *
@@ -140,19 +180,28 @@ pt_png_worker(void *arg)
     return NULL;
 }
 
+static void *
+pt_png_thread(void *arg)
+{
+    pt_png_worker(arg);
+    ((struct pt_png_task *)arg)->cpu_ns = pt_thread_cpu_ns();
+    return NULL;
+}
+
 /* Fan the ranges across pool threads (calling thread = worker 0) and
  * fold per-range failures into the batch-wide decoded prefix — the
- * first rejected index overall (same contract as jpeg_batch.c). */
+ * first rejected index overall (same contract as jpeg_batch.c); adds
+ * the pool threads' CPU to *pool_cpu_ns. */
 static Py_ssize_t
 pt_png_run(struct pt_png_task *tasks, Py_ssize_t n_tasks,
-           Py_ssize_t n_views)
+           Py_ssize_t n_views, long long *pool_cpu_ns)
 {
     pthread_t tids[PT_MAX_THREADS];
     int created[PT_MAX_THREADS] = {0};
     Py_ssize_t t, decoded;
 
     for (t = 1; t < n_tasks; t++) {
-        if (pthread_create(&tids[t], NULL, pt_png_worker, &tasks[t]) != 0) {
+        if (pthread_create(&tids[t], NULL, pt_png_thread, &tasks[t]) != 0) {
             tasks[t].fail = tasks[t].lo;
             continue;
         }
@@ -160,8 +209,10 @@ pt_png_run(struct pt_png_task *tasks, Py_ssize_t n_tasks,
     }
     pt_png_worker(&tasks[0]);
     for (t = 1; t < n_tasks; t++) {
-        if (created[t])
+        if (created[t]) {
             pthread_join(tids[t], NULL);
+            *pool_cpu_ns += tasks[t].cpu_ns;
+        }
     }
     decoded = n_views;
     for (t = 0; t < n_tasks; t++) {
@@ -181,9 +232,14 @@ decode_png_batch(PyObject *self, PyObject *args)
     Py_buffer *views = NULL;
     int height, width;
     int threads_arg = 0;
+    PyObject *cpu_out = NULL;
+    long long pool_cpu_ns = 0;
 
     (void)self;
-    if (!PyArg_ParseTuple(args, "OO|i", &cells, &out_obj, &threads_arg))
+    if (!PyArg_ParseTuple(args, "OO|iO", &cells, &out_obj, &threads_arg,
+                          &cpu_out))
+        return NULL;
+    if (pt_add_cpu_out(cpu_out, 0) != 0)
         return NULL;
     if (PyObject_GetBuffer(out_obj, &out_view,
                            PyBUF_WRITABLE | PyBUF_ND
@@ -254,7 +310,7 @@ decode_png_batch(PyObject *self, PyObject *args)
             tasks[t].width = width;
         }
         Py_BEGIN_ALLOW_THREADS
-        decoded = pt_png_run(tasks, n_tasks, n_views);
+        decoded = pt_png_run(tasks, n_tasks, n_views, &pool_cpu_ns);
         Py_END_ALLOW_THREADS
 
         for (i = 0; i < n_views; i++)
@@ -262,15 +318,18 @@ decode_png_batch(PyObject *self, PyObject *args)
     }
     PyMem_Free(views);
     PyBuffer_Release(&out_view);
+    if (pt_add_cpu_out(cpu_out, pool_cpu_ns) != 0)
+        return NULL;
     return PyLong_FromSsize_t(decoded);
 }
 
 static PyMethodDef png_batch_methods[] = {
     {"decode_png_batch", decode_png_batch, METH_VARARGS,
-     "decode_png_batch(cells, out, threads=0): batched RGB PNG decode "
-     "into a preallocated (N,H,W,3) uint8 array; returns the decoded "
-     "prefix count. threads > 1 fans the cells across an internal "
-     "pthread pool (GIL released)"},
+     "decode_png_batch(cells, out, threads=0, cpu_out=None): batched RGB "
+     "PNG decode into a preallocated (N,H,W,3) uint8 array; returns the "
+     "decoded prefix count. threads > 1 fans the cells across an internal "
+     "pthread pool (GIL released); cpu_out, a writable int64 buffer, "
+     "gains the pool threads' CPU ns"},
     {NULL, NULL, 0, NULL}
 };
 

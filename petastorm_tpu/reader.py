@@ -20,6 +20,7 @@ import warnings
 
 from petastorm_tpu.arrow_worker import RowGroupWorker, defer_config_ok
 from petastorm_tpu.telemetry import note_consumer_wait, span, tracing
+from petastorm_tpu.telemetry.spans import timed
 from petastorm_tpu.cache import LocalDiskCache, NullCache
 from petastorm_tpu.errors import MetadataError, NoDataAvailableError
 from petastorm_tpu.etl.dataset_metadata import (
@@ -635,26 +636,28 @@ class Reader:
             self._started = True
 
     def _pull_result(self):
-        """One pool result under the ``queue_wait`` stage span; blocked
+        """One pool result under the ``queue_wait`` stage span, whose
+        seconds leave out the pool's own work inside the call (the process
+        pool's ``deserialize``, the dummy pool's inline decode); blocked
         time above the noise floor feeds the stall attributor as consumer
         wait (= producer-bound evidence). With tracing on, the wait is
         also stamped onto the ARRIVED item's trace (the context is
         re-derived from the result's item_index/epoch — sampling is
         deterministic, so no wire change on the result path) and the
         producer-bound auto-dump trigger is polled."""
-        with span('queue_wait'):
-            t0 = time.monotonic()
-            result = None
-            try:
+        waiting = timed('queue_wait')
+        result = None
+        try:
+            with waiting:
                 result = self._pool.get_results()
-                return result
-            finally:
-                waited = time.monotonic() - t0
-                if waited > _PULL_NOTE_FLOOR_S:
-                    note_consumer_wait(waited)
-                if tracing.trace_enabled():
-                    self._note_trace_pull(result, waited)
-                    tracing.maybe_autodump()
+            return result
+        finally:
+            waited = waiting.seconds
+            if waited > _PULL_NOTE_FLOOR_S:
+                note_consumer_wait(waited)
+            if tracing.trace_enabled():
+                self._note_trace_pull(result, waited)
+                tracing.maybe_autodump()
 
     def _note_trace_pull(self, result, waited):
         item_index = getattr(result, 'item_index', None)
@@ -664,8 +667,9 @@ class Reader:
             epoch = result.get('epoch')
         ctx = tracing.ctx_for(item_index, epoch, self.cur_shard)
         if ctx is not None:
-            tracing.record_complete('queue_wait', time.time() - waited,
-                                    waited, ctx, track='consumer')
+            end = time.perf_counter_ns()
+            tracing.record_complete('queue_wait', end - int(waited * 1e9),
+                                    end, ctx, track='consumer')
 
     def __next__(self):
         if self._stopped:

@@ -565,6 +565,9 @@ def main(argv=None):
                              "the dispatcher's fleet view)")
     parser.add_argument('-v', '--verbose', action='store_true')
     args = parser.parse_args(argv)
+    # this process decodes for others: its CPU ships as worker CPU
+    from petastorm_tpu.telemetry.registry import set_process_role
+    set_process_role('worker')
     if args.cache_dir:
         knobs.set_env('PETASTORM_TPU_DECODED_CACHE_DIR', args.cache_dir)
     if args.obs_port is not None:

@@ -55,14 +55,15 @@ CRITPATH_AGREEMENT = 'petastorm_tpu_critpath_agreement_total'
 #: instant as self-time and the rest read as overlapped slack. Productive
 #: compute outranks I/O (a fetch running under decode is the overlap
 #: working as designed), I/O outranks staging bookkeeping, and the pure
-#: waits (``queue_wait``, ``ventilate``) come last — they are never
-#: load-bearing while anything else runs.
+#: waits (``queue_wait``, ``ventilate``, the loader's two) come last —
+#: they are never load-bearing while anything else runs.
 _PRIORITY = (
     'decode_fused', 'decode', 'late_materialize', 'transform', 'filter',
     'collate', 'pack', 'encode', 'write_flush', 'compact', 'cache_fill',
-    'cache_hit_read', 'io', 'readahead_fetch', 'rowgroup_prune',
-    'stage_fill', 'h2d_dispatch', 'h2d', 'h2d_ready', 'autotune',
-    'ventilate', 'queue_wait',
+    'cache_hit_read', 'serialize', 'deserialize', 'io', 'readahead_fetch',
+    'rowgroup_prune', 'stage_fill', 'h2d_dispatch', 'h2d', 'h2d_ready',
+    'autotune', 'ventilate', 'queue_wait', 'stage_backpressure',
+    'loader_next',
 )
 _RANK = {stage: i for i, stage in enumerate(_PRIORITY)}
 # any canonical stage missing from the explicit order sorts after it

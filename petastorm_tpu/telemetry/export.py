@@ -7,9 +7,9 @@ All three read a :class:`~petastorm_tpu.telemetry.registry.MetricsRegistry`
 import json
 import time
 
-from petastorm_tpu.telemetry.registry import get_registry
+from petastorm_tpu.telemetry.registry import PROCESS_CPU, get_registry
 from petastorm_tpu.telemetry.spans import (
-    STAGE_CALLS, STAGE_SECONDS, STAGES,
+    STAGE_CALLS, STAGE_CPU_SECONDS, STAGE_SECONDS, STAGES,
 )
 
 #: stall-verdict horizon in sampling windows (~30s at the 0.5s default):
@@ -162,16 +162,24 @@ def pipeline_report(registry=None, wall_time_s=None, baseline=None,
         explain (the dummy-reader benchmark asserts ≥0.95). Without it,
         shares are relative to the summed stage time (worker stages run in
         parallel threads, so their sum can legitimately exceed any wall).
-    :param baseline: an earlier ``registry.snapshot()``; stage seconds are
-        reported as the increase since it (scoping a report to one
-        measurement window).
+    :param baseline: an earlier ``registry.snapshot()``; stage seconds,
+        stage CPU and process CPU are reported as the increase since it
+        (scoping a report to one measurement window).
+
+    Each stage carries ``cpu_seconds`` beside its wall ``seconds`` (its
+    own CPU, native pool threads included; telemetry/spans.py), and
+    ``process_cpu`` holds the processes' CPU by role (``consumer``,
+    ``worker``): the share of the second that the first explains is how
+    much of the host's CPU the spans account for.
     :param attributor: stall attributor to read windows from (default: the
         process-wide one).
     """
     from petastorm_tpu.telemetry.stall import get_attributor
     registry = registry or get_registry()
     attributor = attributor or get_attributor()
+    registry.refresh_process_cpu()
     seconds = registry.counters_with_prefix(STAGE_SECONDS)
+    cpu = registry.counters_with_prefix(STAGE_CPU_SECONDS)
     calls = registry.counters_with_prefix(STAGE_CALLS)
     base_seconds = (baseline or {}).get('counters', {})
     base_calls = base_seconds
@@ -187,12 +195,23 @@ def pipeline_report(registry=None, wall_time_s=None, baseline=None,
         stage = _stage_of(key)
         if stage in stages:
             stages[stage]['calls'] = int(value - base_calls.get(key, 0))
+    for key, value in cpu.items():
+        stage = _stage_of(key)
+        if stage in stages:
+            stages[stage]['cpu_seconds'] = max(
+                value - base_seconds.get(key, 0.0), 0.0)
     total = sum(s['seconds'] for s in stages.values())
     denominator = wall_time_s if wall_time_s else total
     for stage in stages.values():
         stage.setdefault('calls', 0)
+        stage.setdefault('cpu_seconds', 0.0)
         stage['share'] = (stage['seconds'] / denominator
                           if denominator else 0.0)
+    process_cpu = {}
+    for key, value in registry.counters_with_prefix(PROCESS_CPU).items():
+        role = _label_of(key, 'role')
+        if role is not None:
+            process_cpu[role] = max(value - base_seconds.get(key, 0.0), 0.0)
 
     producer_wait, consumer_wait = attributor.totals()
     report = {
@@ -200,6 +219,7 @@ def pipeline_report(registry=None, wall_time_s=None, baseline=None,
                               key=lambda kv: -kv[1]['seconds'])),
         'stage_order': list(STAGES),
         'total_stage_seconds': round(total, 6),
+        'process_cpu': process_cpu,
         'wall_time_s': wall_time_s,
         'attributed_fraction': (round(total / wall_time_s, 4)
                                 if wall_time_s else None),
@@ -650,9 +670,18 @@ def format_pipeline_report(report):
     ordered += [s for s in report['stages'] if s not in ordered]
     for stage in ordered:
         info = report['stages'][stage]
-        lines.append('  %-10s %8.3fs  %5.1f%%  (%d calls)'
+        lines.append('  %-10s %8.3fs  %5.1f%%  (%d calls, %.3fs cpu)'
                      % (stage, info['seconds'], 100 * info['share'],
-                        info['calls']))
+                        info['calls'], info.get('cpu_seconds', 0.0)))
+    process_cpu = report.get('process_cpu')
+    if process_cpu:
+        stage_cpu = sum(i.get('cpu_seconds', 0.0)
+                        for i in report['stages'].values())
+        spent = sum(process_cpu.values())
+        lines.append('  process cpu %s: stages explain %.1f%%'
+                     % (', '.join('%s %.3fs' % kv
+                                  for kv in sorted(process_cpu.items())),
+                        100 * stage_cpu / spent if spent else 0.0))
     if report['wall_time_s']:
         lines.append('  attributed %5.1f%% of %.3fs wall'
                      % (100 * (report['attributed_fraction'] or 0.0),

@@ -13,21 +13,56 @@ artifact without re-running anything.
 Events are plain dicts already shaped like Chrome trace events
 (https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU):
 ``{'name', 'ph', 'ts', 'dur', 'pid', 'tid', 'args'}`` with ``ts``/``dur``
-in microseconds of wall time (``time.time()``, so events from different
-hosts/processes land on one comparable timeline) and ``tid`` a TRACK LABEL
-string (e.g. ``worker-3``/``consumer``); :func:`export_chrome_trace`
-interns labels to integer tids and emits ``thread_name`` metadata, giving
-Perfetto one named track per worker/stage.
+in microseconds and ``tid`` a TRACK LABEL string (e.g.
+``worker-3``/``consumer``). ``ts`` is on this process's monotonic clock
+(``time.perf_counter_ns()``, the clock the stage spans and the timeline
+stamp): a worker's events are moved onto it when they arrive
+(:func:`rebase`; a no-op from the same host, whose monotonic clock is
+shared). :func:`export_chrome_trace` converts to wall time through the
+offset each process reads once (:data:`CLOCK_OFFSET_US`), so events from
+different hosts land on one comparable timeline, and interns labels to
+integer tids with ``thread_name`` metadata, giving Perfetto one named
+track per worker/stage.
 """
 
 import collections
 import json
+import socket
 import threading
+import time
 
 #: default ring capacity (events per process); at ~10 events per row-group
 #: this covers the most recent ~2k items — minutes of timeline at
 #: production rates, a few MB of small dicts
 DEFAULT_CAPACITY = 20000
+
+
+#: this process's wall clock minus its monotonic clock, in µs, read once
+CLOCK_OFFSET_US = time.time() * 1e6 - time.perf_counter_ns() / 1e3
+_HOST = socket.gethostname()
+
+
+def clock_stamp():
+    """What a worker sends beside its events so the receiver can put them
+    on its own clock: ``[host, CLOCK_OFFSET_US]``."""
+    return [_HOST, CLOCK_OFFSET_US]
+
+
+def rebase(events, stamp):
+    """Move ``events`` recorded by the process that sent ``stamp`` onto
+    this process's monotonic clock, in place. Events from this host are on
+    it already; from another host they shift by the two processes' wall
+    offsets. A missing ``stamp`` marks a sender that stamped wall time."""
+    if stamp is None:
+        shift = -CLOCK_OFFSET_US
+    else:
+        host, offset = stamp
+        shift = 0.0 if host == _HOST else offset - CLOCK_OFFSET_US
+    if shift:
+        for event in events:
+            if 'ts' in event:
+                event['ts'] += shift
+    return events
 
 
 class FlightRecorder:
@@ -97,7 +132,7 @@ def reset_recorder():
 def export_chrome_trace(path_or_file, events=None):
     """Write ``events`` (default: the process-wide recorder's snapshot) as
     Chrome trace-event JSON, viewable in Perfetto (ui.perfetto.dev) or
-    ``chrome://tracing``.
+    ``chrome://tracing``, with ``ts`` in µs of wall time.
 
     Track-label ``tid`` strings are interned to integers per ``pid`` and
     announced with ``thread_name`` metadata events, so the viewer shows one
@@ -114,6 +149,8 @@ def export_chrome_trace(path_or_file, events=None):
         if tid is None:
             tid = tids[(pid, label)] = len(tids) + 1
         record = dict(event, pid=pid, tid=tid)
+        if 'ts' in record:
+            record['ts'] = record['ts'] + CLOCK_OFFSET_US
         out.append(record)
     meta = [{'name': 'thread_name', 'ph': 'M', 'pid': pid, 'tid': tid,
              'args': {'name': label}}

@@ -18,6 +18,22 @@ Design constraints (docs/telemetry.md):
 
 import bisect
 import threading
+import time
+
+#: user + system CPU seconds of a process, by its ``role``: ``consumer`` for
+#: the process that reads (refreshed whenever its registry is snapshotted),
+#: ``worker`` for a decode worker process (refreshed just before each delta
+#: frame it ships, so its process CPU and its stage CPU arrive together)
+PROCESS_CPU = 'petastorm_tpu_process_cpu_seconds_total'
+_process_role = 'consumer'
+
+
+def set_process_role(role):
+    """Name the role this process's CPU is counted under
+    (:data:`PROCESS_CPU`); a decode worker process calls it once at
+    start."""
+    global _process_role
+    _process_role = role
 
 #: default histogram buckets (seconds): spans from ~0.1ms row-group ops to
 #: multi-second stalls; the +Inf bucket is implicit.
@@ -143,6 +159,9 @@ class MetricsRegistry:
         self._delta_counters = {}
         self._delta_histograms = {}
         self._delta_gauges = {}
+        # this process's CPU seconds already counted into PROCESS_CPU
+        self._process_cpu_lock = threading.Lock()
+        self._process_cpu_seen = 0.0
 
     # -- metric accessors (create on first use) ------------------------------
 
@@ -190,8 +209,25 @@ class MetricsRegistry:
         return {k: g.value for k, g in list(self._gauges.items())
                 if k.startswith(prefix)}
 
+    def refresh_process_cpu(self):
+        """Add this process's CPU since the last refresh to
+        :data:`PROCESS_CPU` under the process's role. Only the process-wide
+        registry counts it, and only with telemetry on."""
+        if self is not _global_registry:
+            return
+        from petastorm_tpu.telemetry.spans import metrics_disabled
+        if metrics_disabled():
+            return
+        used = time.process_time()   # user + system, every thread
+        with self._process_cpu_lock:
+            spent = used - self._process_cpu_seen
+            self._process_cpu_seen = max(used, self._process_cpu_seen)
+        if spent > 0:
+            self.counter(PROCESS_CPU, role=_process_role).inc(spent)
+
     def snapshot(self):
         """Full state as a JSON-serializable dict."""
+        self.refresh_process_cpu()
         return {
             'counters': {k: c.value for k, c in list(self._counters.items())},
             'gauges': {k: g.value for k, g in list(self._gauges.items())},
@@ -207,6 +243,11 @@ class MetricsRegistry:
         ship their current value (last-writer-wins on merge). Returns None
         when nothing changed — callers piggybacking deltas on existing
         messages can skip the payload entirely."""
+        if _process_role == 'worker':
+            # a worker's process CPU rides the frame that ships its stage
+            # CPU; a consumer's is refreshed by snapshot() alone, so its
+            # deltas still read None when nothing else moved
+            self.refresh_process_cpu()
         delta = {'counters': {}, 'gauges': {}, 'histograms': {}}
         for key, c in list(self._counters.items()):
             value = c.value
@@ -324,17 +365,20 @@ def dump_delta_frame():
 
     Per-item trace events piggyback here too: when the flight recorder
     holds events (worker-side stage/attempt events of traced items), the
-    frame carries them under ``trace_events`` and the recorder is drained
-    — the trace layer reuses the metrics' channel instead of adding one."""
+    frame carries them under ``trace_events`` with the sender's clock
+    (``trace_clock``, :func:`~petastorm_tpu.telemetry.recorder.clock_stamp`)
+    and the recorder is drained — the trace layer reuses the metrics'
+    channel instead of adding one."""
     import dill
     try:
         delta = get_registry().collect_delta()
-        from petastorm_tpu.telemetry.recorder import get_recorder
+        from petastorm_tpu.telemetry.recorder import clock_stamp, get_recorder
         recorder = get_recorder()
         if len(recorder):
             delta = delta or {'counters': {}, 'gauges': {},
                               'histograms': {}}
             delta['trace_events'] = recorder.drain()
+            delta['trace_clock'] = clock_stamp()
         return dill.dumps(delta) if delta else b''
     except Exception:  # noqa: BLE001 - telemetry is advisory
         return b''
@@ -346,7 +390,8 @@ def load_delta_frame(frame):
     freshness, nothing more — it must never take a data channel down).
 
     The shape check is strict — EXACTLY the three delta keys (plus an
-    optional ``trace_events`` LIST), the three all dicts, at least one of
+    optional ``trace_events`` LIST and its ``trace_clock``), the three all
+    dicts, at least one of
     the fields non-empty — because the service dispatcher uses it to
     tell a metrics frame from a result frame sent by a pre-telemetry
     worker build (the wire has no version marker); a permissive check
@@ -361,7 +406,8 @@ def load_delta_frame(frame):
     if not isinstance(delta, dict):
         return None
     base_keys = {'counters', 'gauges', 'histograms'}
-    if set(delta) not in (base_keys, base_keys | {'trace_events'}):
+    if set(delta) not in (base_keys, base_keys | {'trace_events'},
+                          base_keys | {'trace_events', 'trace_clock'}):
         return None
     if not all(isinstance(delta[k], dict) for k in base_keys):
         return None
@@ -395,8 +441,10 @@ def _merge_worker_delta(delta):
         # a remote worker's flight-recorder batch: fold it into THIS
         # process's recorder, where the whole distributed timeline
         # accumulates for export (dump_trace / --trace-out)
-        from petastorm_tpu.telemetry.recorder import get_recorder
-        get_recorder().add_many(e for e in events if isinstance(e, dict))
+        from petastorm_tpu.telemetry.recorder import get_recorder, rebase
+        get_recorder().add_many(rebase(
+            [e for e in events if isinstance(e, dict)],
+            delta.get('trace_clock')))
     counters = delta.get('counters', {})
     # import here: registry must stay importable before the package's
     # __init__ finishes binding the sibling modules

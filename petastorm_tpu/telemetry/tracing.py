@@ -26,6 +26,13 @@ hook; the hot path pays exactly what it paid before this module existed
 (``PETASTORM_TPU_TRACE_SAMPLE=1/N``) is deterministic on the item
 sequence number, so the consumer can re-derive a result's context
 (:func:`ctx_for`) without any wire change on the result path.
+
+The timeline (:func:`timeline`) is the other use of the same span hook:
+armed from code for a block, it records EVERY stage span of this process
+— start, end and CPU, on ``time.perf_counter_ns()`` — into the flight
+recorder, whether or not an item is traced, so a profile taken on the
+same clock (a device trace) can be read against what each thread of the
+pipeline was doing.
 """
 
 import atexit
@@ -56,7 +63,8 @@ _stride = None
 # rerun) in one process never collide
 _run_id = uuid.uuid4().hex[:8]
 
-_state = threading.local()     # .ctx / .track of the active item, if any
+_state = threading.local()     # .ctx / .track of the active item, if any;
+                               # .batch: the loader batch the thread serves
 
 
 def trace_enabled():
@@ -97,6 +105,9 @@ def refresh_trace():
     global _autodump_fired, _autodump_last_check
     _autodump_fired = False
     _autodump_last_check = 0.0
+    global _hook_installed, _timeline_pid
+    _hook_installed = False
+    _timeline_pid = None
     spans.set_trace_hook(None)
     # refresh() is a main-thread call in real entry points: the chance to
     # (re)arm the SIGUSR1/atexit dump hooks for a just-set dump path
@@ -195,17 +206,17 @@ class _Attempt(_Activation):
 
     def __enter__(self):
         super().__enter__()
-        self._t0 = time.time()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc_val, exc_tb):
-        dur = time.time() - self._t0
+        end = time.perf_counter_ns()
         ctx, track = self._ctx, _state.track
         super().__exit__(exc_type, exc_val, exc_tb)
         # worker rides in args too (not just the track label): consumers
         # of the event list — slowest_items, the benchmark's printout —
         # need it without reconstructing the track interning
-        record_complete('attempt', self._t0, dur, ctx, track,
+        record_complete('attempt', self._t0, end, ctx, track,
                         worker=track,
                         error=exc_type.__name__ if exc_type else None)
         return False
@@ -246,9 +257,10 @@ def _ctx_args(ctx, extra):
     return args
 
 
-def record_complete(name, wall_start, dur_s, ctx=None, track=None, **extra):
-    """One Chrome 'X' (complete) event on ``ctx``'s trace. ``wall_start``
-    is ``time.time()`` at the beginning; no-op without a context."""
+def record_complete(name, start_ns, end_ns, ctx=None, track=None, **extra):
+    """One Chrome 'X' (complete) event on ``ctx``'s trace, from
+    ``start_ns`` to ``end_ns`` of ``time.perf_counter_ns()``; no-op
+    without a context."""
     ctx = ctx if ctx is not None else current_context()
     if ctx is None:
         return
@@ -256,7 +268,7 @@ def record_complete(name, wall_start, dur_s, ctx=None, track=None, **extra):
         track = getattr(_state, 'track', None) or 'main'
     get_recorder().add({
         'name': name, 'ph': 'X', 'cat': 'petastorm_tpu',
-        'ts': wall_start * 1e6, 'dur': dur_s * 1e6,
+        'ts': start_ns / 1e3, 'dur': (end_ns - start_ns) / 1e3,
         'pid': os.getpid(), 'tid': track,
         'args': _ctx_args(ctx, extra),
     })
@@ -269,19 +281,43 @@ def record_instant(name, ctx, track, **extra):
         return
     get_recorder().add({
         'name': name, 'ph': 'i', 's': 'p', 'cat': 'petastorm_tpu',
-        'ts': time.time() * 1e6,
+        'ts': time.perf_counter_ns() / 1e3,
         'pid': os.getpid(), 'tid': track,
         'args': _ctx_args(ctx, extra),
     })
 
 
-def _span_trace_hook(stage, elapsed_s):
-    """Installed into :mod:`spans` while a trace context is active in this
-    process: every canonical stage span also lands a trace event."""
+#: ``cat`` of the events the armed timeline records
+TIMELINE_CAT = 'timeline'
+
+# this process's pid while the timeline is armed, else None
+_timeline_pid = None
+
+
+def _span_trace_hook(stage, start_ns, end_ns, cpu_ns, thread):
+    """Installed into :mod:`spans` while a trace context has been active in
+    this process or the timeline is armed: a span lands a trace event on
+    the active item's trace, and every span does while the timeline is
+    armed."""
     ctx = getattr(_state, 'ctx', None)
-    if ctx is None:
+    pid = _timeline_pid
+    if pid is None:
+        if ctx is not None:
+            record_complete(stage, start_ns, end_ns, ctx)
         return
-    record_complete(stage, time.time() - elapsed_s, elapsed_s, ctx)
+    args = {'cpu_ns': cpu_ns}
+    batch = getattr(_state, 'batch', None)
+    if batch is not None:
+        args['batch'] = batch
+    if ctx is not None:
+        args = _ctx_args(ctx, args)
+    get_recorder().add({
+        'name': stage, 'ph': 'X', 'cat': TIMELINE_CAT,
+        'ts': start_ns / 1e3, 'dur': (end_ns - start_ns) / 1e3,
+        'pid': pid, 'tid': (getattr(_state, 'track', None) if ctx is not None
+                            else None) or thread,
+        'args': args,
+    })
 
 
 _hook_installed = False
@@ -292,6 +328,59 @@ def _ensure_span_hook():
     if not _hook_installed or spans._trace_hook is None:
         spans.set_trace_hook(_span_trace_hook)
         _hook_installed = True
+
+
+# -- timeline -----------------------------------------------------------------
+
+
+def tag_batch(seq):
+    """Name the loader batch this thread now works for: timeline events of
+    its spans carry it as ``args['batch']`` (the staging thread's fill,
+    dispatch and decode spans, the consumer's ``loader_next``)."""
+    _state.batch = seq
+
+
+class timeline:
+    """Context manager arming the timeline for its block: every stage span
+    of this process — not only sampled items — lands in the flight
+    recorder as an 'X' event (``cat`` :data:`TIMELINE_CAT`, ``ts``/``dur``
+    in µs of ``time.perf_counter_ns()``, ``tid`` the thread's name,
+    ``args`` ``cpu_ns`` and, where the thread serves a loader batch,
+    ``batch``). Disarmed, the span hook is what it was before (None unless
+    per-item tracing is active). Not an environment knob: a profiler run
+    arms it around the stretch it traces. Arming does not nest.
+    ``start_ns`` / ``end_ns`` bound the block on
+    ``time.perf_counter_ns()``."""
+
+    def __init__(self):
+        self.start_ns = self.end_ns = None
+
+    def __enter__(self):
+        global _timeline_pid
+        _timeline_pid = os.getpid()
+        spans.set_trace_hook(_span_trace_hook)
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc_val, exc_tb):
+        global _timeline_pid
+        self.end_ns = time.perf_counter_ns()
+        _timeline_pid = None
+        spans.set_trace_hook(_span_trace_hook if _hook_installed else None)
+        return False
+
+    def events(self):
+        """The timeline events of the spans of this process that ended in
+        the block (a span that began before it included), from the flight
+        recorder, oldest first; the ring keeps the most recent
+        ``DEFAULT_CAPACITY`` events of every kind."""
+        end = self.end_ns if self.end_ns is not None \
+            else time.perf_counter_ns()
+        lo, hi = self.start_ns / 1e3, end / 1e3
+        pid = os.getpid()
+        return [e for e in get_recorder().snapshot()
+                if e.get('cat') == TIMELINE_CAT and e.get('pid') == pid
+                and lo <= e['ts'] + e['dur'] <= hi]
 
 
 # -- dumps --------------------------------------------------------------------

@@ -42,8 +42,9 @@ from petastorm_tpu import sanitizer
 from petastorm_tpu.serializers import PickleSerializer
 from petastorm_tpu.telemetry import (
     STALL_NOTE_FLOOR_S, dump_delta_frame, load_delta_frame,
-    merge_worker_delta, note_producer_wait, tracing,
+    merge_worker_delta, note_producer_wait, span, tracing,
 )
+from petastorm_tpu.telemetry.registry import set_process_role
 from petastorm_tpu.workers import (
     EmptyResultError, TimeoutWaitingForResultError, WorkerTerminationRequested,
 )
@@ -192,8 +193,18 @@ class ProcessPool:
             # zero-copy memoryviews — what lets the pickle-5 out-of-band
             # result path rebuild ndarrays as views over the wire buffers
             # (no host copy between the socket and the consumer's arrays)
-            frames = self._results_socket.recv_multipart(copy=False)
-            kind = frames[0].bytes
+            with span('deserialize'):
+                frames = self._results_socket.recv_multipart(copy=False)
+                kind = frames[0].bytes
+                if kind == _MSG_RESULT:
+                    payload = [f.buffer for f in frames[1:]]
+                    if sanitizer.sanitize_enabled():
+                        # read-only memoryviews over the receive buffers:
+                        # arrays pickle-5 rebuilds over them come out
+                        # writeable=False, so a consumer's in-place write
+                        # raises instead of corrupting ZMQ's buffers
+                        payload = [b.toreadonly() for b in payload]
+                    return self._serializer.deserialize_frames(payload)
             if kind == _MSG_MARKER:
                 self._processed_items += 1
                 if self._ventilator is not None:
@@ -209,15 +220,6 @@ class ProcessPool:
                 self.stop()
                 self.join()
                 raise self._error
-            if kind == _MSG_RESULT:
-                payload = [f.buffer for f in frames[1:]]
-                if sanitizer.sanitize_enabled():
-                    # read-only memoryviews over the receive buffers:
-                    # arrays pickle-5 rebuilds over them come out
-                    # writeable=False, so a consumer's in-place write
-                    # raises instead of corrupting ZMQ's buffers
-                    payload = [b.toreadonly() for b in payload]
-                return self._serializer.deserialize_frames(payload)
             if kind in (_MSG_READY, _MSG_EXIT):
                 continue
             logger.warning('Unknown pool message type %r', kind)
@@ -306,6 +308,7 @@ def _worker_bootstrap(worker_id, main_pid, work_ep, control_ep, results_ep,
             time.sleep(1.0)
 
     threading.Thread(target=_orphan_monitor, daemon=True).start()
+    set_process_role('worker')
 
     worker_class, worker_args = dill.loads(class_payload)
     serializer = dill.loads(serializer_payload)
@@ -320,20 +323,20 @@ def _worker_bootstrap(worker_id, main_pid, work_ep, control_ep, results_ep,
     results.set_hwm(max(1, results_hwm))
     results.connect(results_ep)
 
-    def send_or_stop(frames):
-        """Stop-aware send (mirrors ThreadPool._publish): a worker parked on
-        a full results channel must still hear the stop broadcast, or every
-        mid-stream shutdown would end in SIGTERM with no clean shutdown().
+    def await_room():
+        """Stop-aware wait for room in the results channel (mirrors
+        ThreadPool._publish): a worker parked on a full results channel
+        must still hear the stop broadcast, or every mid-stream shutdown
+        would end in SIGTERM with no clean shutdown(). This thread is the
+        channel's only sender, so the room it finds is still there for
+        the send that follows.
 
         Time blocked against the channel's HWM is back-pressure from a
         slow consumer; it lands in this worker's registry (producer-wait
         counter) and reaches the consumer with the next marker's delta."""
         start = time.monotonic()
         try:
-            while True:
-                if results.poll(_POLL_INTERVAL_MS, zmq.POLLOUT):
-                    results.send_multipart(frames)
-                    return
+            while not results.poll(_POLL_INTERVAL_MS, zmq.POLLOUT):
                 if control.poll(0) and control.recv() == _CTRL_STOP:
                     raise WorkerTerminationRequested()
         finally:
@@ -341,11 +344,21 @@ def _worker_bootstrap(worker_id, main_pid, work_ep, control_ep, results_ep,
             if blocked > STALL_NOTE_FLOOR_S:
                 note_producer_wait(blocked)
 
+    def send_or_stop(frames):
+        await_room()
+        results.send_multipart(frames)
+
     def publish(value):
         # multipart result: frame 0 the pickle-5 stream, every ndarray
         # payload its own out-of-band frame (serializers.py) — ZMQ sends
-        # straight from the exported buffers, one memcpy per array
-        send_or_stop([_MSG_RESULT] + list(serializer.serialize_frames(value)))
+        # straight from the exported buffers, one memcpy per array. The
+        # framing and that send are the serialize stage; the wait for
+        # room before them is producer wait (the framing itself is cheap,
+        # so framing after the wait delays nothing that matters)
+        await_room()
+        with span('serialize'):
+            results.send_multipart(
+                [_MSG_RESULT] + list(serializer.serialize_frames(value)))
 
     worker = worker_class(worker_id, publish, worker_args)
     worker.initialize()

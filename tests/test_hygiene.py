@@ -75,7 +75,8 @@ def test_span_and_trace_stage_names_are_canonical():
     from petastorm_tpu.analysis.contracts import EVENT_NAMES, STAGES
     whitelist = set()  # intentionally empty today; add with a comment why
     allowed = set(STAGES) | set(EVENT_NAMES) | whitelist
-    recording_calls = ('span', 'record_complete', 'record_instant')
+    recording_calls = ('span', 'timed', 'record_complete',
+                       'record_instant')
     offenders = []
     for rel, source in _package_sources():
         for node in ast.walk(ast.parse(source, filename=rel)):
@@ -104,7 +105,8 @@ def test_every_canonical_stage_is_recorded_somewhere():
     reconstruction assumes every canonical stage CAN appear in a
     trace)."""
     from petastorm_tpu.analysis.contracts import STAGES
-    recording_calls = ('span', 'record_complete', 'record_instant')
+    recording_calls = ('span', 'timed', 'record_complete',
+                       'record_instant')
     recorded = set()
     for rel, source in _package_sources():
         for node in ast.walk(ast.parse(source, filename=rel)):

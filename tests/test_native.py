@@ -560,8 +560,11 @@ class TestNativePngDecoder:
         field = UnischemaField('im', np.uint8, (32, 32, 3), codec, False)
         cells, images = self._png_cells(8, seed=11)
         batch = codec.decode_batch(field, cells)
-        real_calls = [(n, args) for n, args in calls if n > 0]
+        real_calls = [(n, args[:1]) for n, args in calls if n > 0]
         assert real_calls == [(8, (3,))], calls
+        # ... and the buffer the pool threads' CPU comes back in
+        (cpu_out,) = [args[1] for n, args in calls if n > 0]
+        assert cpu_out.dtype == np.int64 and cpu_out[0] >= 0
         # the C pool took the batch, so the Python-side executor was
         # never even created (one pool per batch, docs/env_knobs.md)
         assert codecs._IMAGE_POOL is None

@@ -11,6 +11,7 @@ import pytest
 
 from petastorm_tpu import telemetry as T
 from petastorm_tpu.telemetry.registry import MetricsRegistry, metric_key
+from petastorm_tpu.telemetry import spans
 from petastorm_tpu.telemetry.spans import _NOOP_SPAN
 from petastorm_tpu.telemetry.stall import classify_window
 
@@ -288,8 +289,33 @@ def test_overhead_budget():
             pass
     span_per_call = (time.perf_counter() - start) / n
 
+    # the span read both clocks: its CPU counter moved with its calls
+    registry = T.get_registry()
+    assert registry.counter_value(spans.STAGE_CALLS, stage='decode') >= n
+    assert registry.counter_value(spans.STAGE_CPU_SECONDS,
+                                  stage='decode') > 0
+
+    start = time.perf_counter()
+    for _ in range(n):
+        with spans.timed('loader_next'):
+            pass
+    timed_per_call = (time.perf_counter() - start) / n
+
+    # armed, a span also lands one timeline event in the flight recorder
+    m = n // 4
+    with T.tracing.timeline() as armed:
+        start = time.perf_counter()
+        for _ in range(m):
+            with T.span('decode'):
+                pass
+        armed_per_call = (time.perf_counter() - start) / m
+    assert len(armed.events()) == m
+    assert spans._trace_hook is None
+
     assert counter_per_call < 25e-6, counter_per_call
     assert span_per_call < 50e-6, span_per_call
+    assert timed_per_call < 50e-6, timed_per_call
+    assert armed_per_call < 100e-6, armed_per_call
 
 
 def test_overhead_budget_disabled(monkeypatch):
@@ -303,6 +329,14 @@ def test_overhead_budget_disabled(monkeypatch):
                 pass
         per_call = (time.perf_counter() - start) / n
         assert per_call < 10e-6, per_call
+        # the loader's wait clocks keep the wall clock, and only it
+        start = time.perf_counter()
+        for _ in range(n):
+            with spans.timed('loader_next'):
+                pass
+        timed_per_call = (time.perf_counter() - start) / n
+        assert timed_per_call < 10e-6, timed_per_call
+        assert T.get_registry().snapshot()['counters'] == {}
     finally:
         monkeypatch.delenv('PETASTORM_TPU_METRICS')
         T.refresh_enabled()

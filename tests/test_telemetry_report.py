@@ -9,6 +9,7 @@ import pickle
 import pytest
 
 from petastorm_tpu import telemetry as T
+from petastorm_tpu.telemetry.registry import PROCESS_CPU
 from petastorm_tpu.cache import (
     CACHE_BYTES_EVICTED, CACHE_BYTES_WRITTEN, CACHE_EVICTIONS, CACHE_HITS,
     CACHE_MISSES, LocalDiskCache,
@@ -181,5 +182,9 @@ def test_jsonl_roundtrip_through_cli_snapshot(tmp_path):
     T.write_jsonl_snapshot(path)
     (snap,) = T.read_jsonl_snapshots(path)
     live = T.get_registry().snapshot()
+    # every snapshot refreshes this process's CPU counter, which moves
+    # between the two; the rest must read back exactly
+    cpu = PROCESS_CPU + '{role="consumer"}'
+    assert snap['counters'].pop(cpu) <= live['counters'].pop(cpu)
     assert snap['counters'] == live['counters']
     assert snap['histograms'] == live['histograms']
