@@ -462,8 +462,8 @@ BORROW_CALL_KWARGS = {
 #: dotted expressions denoting borrowed buffer collections — staging-arena
 #: slot memory (recycled after the slot's next transfer retires) and a
 #: deferred image column's encoded cell views (zero-copy over the arrow
-#: data buffer; valid only while the column object — which carries the
-#: owning arrow column — is alive). Any view over them is borrowed.
+#: data buffer or a received frame, which each view's numpy base keeps
+#: alive). Any view over them is borrowed.
 BORROW_ATTRS = frozenset([
     'slot.buffers',
     'column.cells',

@@ -69,8 +69,8 @@ def _binary_cell_views(arrow_col):
 
     Returns None when the column is not binary-typed (caller falls back to
     ``to_pylist``). Null cells become None. The views alias the arrow data
-    buffer, so they are only valid while the source column is alive — the
-    decode loop consumes them immediately within ``_load_rowgroup``.
+    buffer, which each view's numpy ``base`` (the ``pyarrow.Buffer``) keeps
+    alive.
     """
     import pyarrow as pa
     chunks = (arrow_col.chunks if isinstance(arrow_col, pa.ChunkedArray)
@@ -653,8 +653,7 @@ class RowGroupWorker(WorkerBase):
             cells = _binary_cell_views(arrow_col)
             if cells is not None:
                 survivors = [cells[i] for i in row_indices]
-                return self._image_column(field, survivors, arrow_col,
-                                          allow_defer=True)
+                return self._image_column(field, survivors, allow_defer=True)
         return self._decode_column(name, arrow_col.take(row_indices),
                                    allow_defer=True)
 
@@ -685,13 +684,12 @@ class RowGroupWorker(WorkerBase):
                 # arrow data buffer instead of a per-cell bytes copy
                 cells = _binary_cell_views(arrow_col)
                 if cells is not None:
-                    return self._image_column(field, cells, arrow_col,
-                                              allow_defer)
+                    return self._image_column(field, cells, allow_defer)
             return self._stack(decode_batch_with_nulls(
                 field, arrow_col.to_pylist()))
         return self._collate_plain(field, arrow_col, arrow_col.to_pylist())
 
-    def _image_column(self, field, cells, arrow_col, allow_defer):
+    def _image_column(self, field, cells, allow_defer):
         """One image column of one row-group: defer (fused), decode dense
         into a page-aligned slab, or fall back to the per-cell path."""
         shape = field.shape
@@ -704,7 +702,7 @@ class RowGroupWorker(WorkerBase):
                 dense_ok = False
         if self._defer_decode and allow_defer:
             if dense_ok and dtype.kind in 'iuf':
-                return EncodedImageColumn(field, cells, owner=arrow_col)
+                return EncodedImageColumn(field, cells)
             count_fallback('column-shape')
         if dense_ok:
             try:

@@ -17,6 +17,8 @@ from collections import deque
 
 import numpy as np
 
+from petastorm_tpu.fused import count_fallback
+
 
 class ShufflingBufferBase(metaclass=ABCMeta):
     """Row-level buffer contract."""
@@ -250,6 +252,18 @@ class BatchedRandomShufflingBuffer(BatchedShufflingBufferBase):
     add; retrieval gathers ``batch_size`` random rows and compacts the holes
     with tail rows — all vectorized (reference keeps torch tensors and slices
     a randperm, ``pytorch_shuffling_buffer.py:162-291``).
+
+    A still-encoded image column (:class:`~petastorm_tpu.fused.
+    EncodedImageColumn`, the fused-decode carrier) allocates its own
+    storage (``empty``), which gathers and assigns cell views with the
+    ndarray indexing this class uses: adds, gathers and compaction move
+    pointers, not decoded pixel rows, and :meth:`retrieve` hands back an
+    ``EncodedImageColumn`` over the drawn cells for the staging arena to
+    decode straight into its slot. The draws are the same either way, so
+    a seed selects the same rows whether the column arrives encoded or
+    decoded. A column whose chunks arrive some encoded and some decoded
+    (the worker declines to defer a row-group with null cells) goes on
+    decoded, counted as the fused decode's ``mixed-chunks`` decline.
     """
 
     def __init__(self, shuffling_buffer_capacity, min_after_retrieve,
@@ -277,13 +291,14 @@ class BatchedRandomShufflingBuffer(BatchedShufflingBufferBase):
         cap = self._capacity + self._extra_capacity
         self._buffers = {}
         for name, arr in columns.items():
-            arr = np.asarray(arr)
-            self._buffers[name] = np.empty((cap,) + arr.shape[1:], dtype=arr.dtype)
+            self._buffers[name] = (
+                np.empty((cap,) + arr.shape[1:], dtype=arr.dtype)
+                if isinstance(arr, np.ndarray) else arr.empty(cap))
 
     def add_many(self, columns):
         if not self.can_add:
             raise RuntimeError('add_many called on a full or finished buffer')
-        columns = {k: np.asarray(v) for k, v in columns.items()}
+        columns = {k: _as_column(v) for k, v in columns.items()}
         n = _leading_dim(columns)
         if n == 0:
             return
@@ -296,6 +311,8 @@ class BatchedRandomShufflingBuffer(BatchedShufflingBufferBase):
                                     self._size))
         for name, arr in columns.items():
             buf = self._buffers[name]
+            if isinstance(buf, np.ndarray) != isinstance(arr, np.ndarray):
+                buf, arr = self._go_decoded(name, arr)
             # Widen the buffer when a later chunk needs a wider dtype (e.g.
             # '<U3' → '<U10', int32 → int64): plain assignment would silently
             # truncate/wrap instead.
@@ -306,6 +323,22 @@ class BatchedRandomShufflingBuffer(BatchedShufflingBufferBase):
                 self._buffers[name] = buf
             buf[self._size:self._size + n] = arr
         self._size += n
+
+    def _go_decoded(self, name, chunk):
+        """One side of column ``name`` is encoded and the other decoded:
+        the buffered column or the ``chunk`` being added. The column goes
+        on decoded — encoded buffered cells decode once, here, into a
+        dense buffer of the field's shape and dtype; an encoded chunk
+        decodes on its add. Returns the buffer and the chunk to assign.
+        """
+        count_fallback('mixed-chunks')
+        buf = self._buffers[name]
+        if isinstance(chunk, np.ndarray):
+            dense = np.empty(buf.shape, buf.dtype)
+            buf[:self._size].decode_into(dense[:self._size])
+            self._buffers[name] = dense
+            return dense, chunk
+        return buf, chunk.materialize()
 
     def retrieve(self):
         if not self.can_retrieve:
@@ -329,6 +362,9 @@ class BatchedRandomShufflingBuffer(BatchedShufflingBufferBase):
         movers = np.flatnonzero(~sel_mask[new_size:]) + new_size
         for buf in self._buffers.values():
             buf[holes] = buf[movers]
+            if not isinstance(buf, np.ndarray):
+                # an encoded cell left past the end would pin its frame
+                buf[new_size:self._size] = None
 
     def finish(self):
         self._done = True
@@ -346,6 +382,13 @@ class BatchedRandomShufflingBuffer(BatchedShufflingBufferBase):
     @property
     def size(self):
         return self._size
+
+
+def _as_column(values):
+    """``values`` as an ndarray, unless it is a column type that keeps
+    its own storage (the still-encoded image column of
+    :mod:`petastorm_tpu.fused`)."""
+    return values if hasattr(values, 'decode_into') else np.asarray(values)
 
 
 def _leading_dim(columns):

@@ -30,24 +30,37 @@ arena"):
   at their final host address, by the native batch decoders' internal
   C-level thread pool.
 
+Shuffled rows take the same route: the loader's random shuffling buffer
+(``buffers.BatchedRandomShufflingBuffer``) stores an encoded column as
+an :class:`EncodedImageColumn` over an object array of cell views
+(:meth:`EncodedImageColumn.empty`) and gathers and assigns cells, not
+pixels, so only the rows a batch draws are decoded, once, into the slot.
+
 Eligibility is decided at two gates and every decline falls back to the
 classic worker-side batched decode, counted in
 ``petastorm_tpu_fused_decode_fallbacks_total{reason=…}`` (the
 "decode is batched but not fused" runbook in docs/troubleshoot.md reads
 these): the worker defers only fixed-shape, numeric, null-free image
 columns on the no-transform/no-ngram/no-cache path; the loader
-materializes early when staging is off, rows are shuffled, or a dtype
-cast retargets the column.
+materializes early when staging is off, batching is bucketed, or a
+dtype cast retargets the column; the random buffer decodes a column
+whose chunks arrive some encoded and some decoded (``mixed-chunks``).
 
 Ownership contract (pipesan): ``EncodedImageColumn.cells`` hold ZERO-COPY
 views over the arrow column's data buffer — borrowed memory, registered
 as a borrow source in ``analysis/contracts.py`` (``column.cells``). The
-column object carries its ``owner`` (the arrow column) so the views
-outlive every hop by construction, and pickling across a process/service
-pool materializes the cells into owned copies.
+one lifetime rule is numpy's ``base`` chain: each cell view holds the
+buffer it views (the arrow buffer in the worker, the received frame
+after a pool hop), so a cell keeps its memory alive wherever it goes,
+through slices, gathers and the shuffling buffer, and nothing else
+needs to. Pickling across a process/service pool packs the cells into
+one contiguous buffer that travels as a single pickle-5 out-of-band
+frame (``PickleSerializer.serialize_frames``; in band under the
+one-frame ``serialize``); the receiver's cells are views over that frame.
 """
 
 import logging
+import pickle
 
 import numpy as np
 
@@ -103,23 +116,24 @@ class EncodedImageColumn:
 
     ``cells`` is a sequence of encoded bytes-like objects (zero-copy
     ``np.uint8`` views over the arrow data buffer on the in-process
-    path); ``owner`` pins the arrow column those views alias. Cells may
-    not be None here — the worker's eligibility gate routes nullable
+    path, over the received frame after a pool hop, an object array of
+    such views after the shuffling buffer's gather), each keeping the
+    memory it views alive through its numpy ``base``. Cells may not be
+    None here — the worker's eligibility gate routes nullable
     row-groups to the classic decode so null semantics never change —
     but :meth:`decode_into` still zero-fills defensively via
-    ``decode_batch_with_nulls``.
+    ``decode_batch_with_nulls``. A column made by :meth:`empty` holds
+    None in the slots not yet assigned.
     """
 
-    __slots__ = ('field', 'cells', 'owner')
+    __slots__ = ('field', 'cells')
 
-    def __init__(self, field, cells, owner=None):
+    def __init__(self, field, cells):
         self.field = field
-        # Intentional transfer of the worker's borrowed cell views: the
-        # arrow column that owns their memory rides along in `owner`, so
-        # the views stay valid for this object's whole lifetime (and a
-        # cross-process pickle materializes owned copies).  # pipesan: owns
+        # Intentional transfer of the worker's borrowed cell views: each
+        # view's numpy base chain holds the buffer it aliases, so the
+        # views stay valid for this object's whole lifetime.  # pipesan: owns
         self.cells = cells
-        self.owner = owner
 
     # -- ndarray-like surface -------------------------------------------------
 
@@ -143,14 +157,31 @@ class EncodedImageColumn:
     def __getitem__(self, index):
         """Slicing returns a VIEW column over the same cells (the noop
         re-batcher splits chunks with ``col[:take]`` / ``col[take:]``);
-        anything but a slice is a contract error — per-row access means
-        some consumer thinks this is decoded data."""
-        if not isinstance(index, slice):
-            raise TypeError(
-                'EncodedImageColumn is encoded data; decode it '
-                '(decode_into/materialize) before per-row indexing')
-        return EncodedImageColumn(self.field, self.cells[index],
-                                  owner=self.owner)
+        an integer index array gathers those cells (the random shuffling
+        buffer's draw). A scalar index is a contract error — per-row
+        access means some consumer thinks this is decoded data."""
+        if isinstance(index, slice):
+            return EncodedImageColumn(self.field, self.cells[index])
+        if isinstance(index, np.ndarray) and index.dtype.kind in 'iu':
+            return EncodedImageColumn(self.field,
+                                      _object_cells(self.cells)[index])
+        raise TypeError(
+            'EncodedImageColumn is encoded data; decode it '
+            '(decode_into/materialize) before per-row indexing')
+
+    def __setitem__(self, index, value):
+        """Assign the cells of the column ``value`` to the slots
+        ``index`` selects, or, with ``value`` None, empty them (a cell
+        left in a slot would keep the buffer it views alive). Only a
+        column made by :meth:`empty` is written to."""
+        self.cells[index] = (None if value is None
+                             else _object_cells(value.cells))
+
+    def empty(self, n):
+        """A column of this field with ``n`` empty slots, for
+        :meth:`__setitem__` to fill: the random shuffling buffer's
+        storage for an encoded column."""
+        return EncodedImageColumn(self.field, np.full(n, None, object))
 
     # -- decode ---------------------------------------------------------------
 
@@ -164,23 +195,52 @@ class EncodedImageColumn:
     def materialize(self):
         """Decode to a fresh page-aligned owned batch — the fallback for
         consumers that cannot provide a destination (staging disabled,
-        shuffled rows, dtype recast)."""
+        bucketed batching, dtype recast)."""
         out = alloc_column_slab(self.shape, self.dtype)
         return self.decode_into(out)
 
     # -- pickling (process/service pools) ------------------------------------
 
-    def __getstate__(self):
-        # drop the arrow owner: the cells pickle as owned byte copies, so
-        # the receiving process needs (and must not pay for) no second
-        # copy of the arrow buffer riding along
-        return (self.field,
-                [None if c is None else bytes(c) for c in self.cells])
-
-    def __setstate__(self, state):
-        self.field, self.cells = state
-        self.owner = None
+    def __reduce_ex__(self, protocol):
+        # the cells travel as ONE contiguous buffer plus offsets: under
+        # pickle 5 with a buffer callback (the process pool's
+        # serialize_frames) the PickleBuffer ships as one out-of-band
+        # frame, not one in-band bytes object per cell; older protocols,
+        # which cannot carry a PickleBuffer, take the buffer as bytes.
+        # The receiver's cells view the payload
+        views = [None if c is None else np.frombuffer(c, np.uint8)
+                 for c in self.cells]
+        present = [v for v in views if v is not None]
+        offsets = np.zeros(len(views) + 1, np.int64)
+        offsets[1:] = np.cumsum([0 if v is None else len(v) for v in views])
+        data = np.concatenate(present) if present else np.empty(0, np.uint8)
+        payload = pickle.PickleBuffer(data) if protocol >= 5 \
+            else data.tobytes()
+        nulls = tuple(i for i, v in enumerate(views) if v is None)
+        # offsets in band (bytes): only the cell payload earns a frame
+        return _unpack_cells, (self.field, payload, offsets.tobytes(), nulls)
 
     def __repr__(self):
         return ('EncodedImageColumn(%r, n=%d, shape=%s)'
                 % (self.field.name, len(self.cells), self.shape))
+
+
+def _object_cells(cells):
+    """``cells`` as a 1-d object array, one cell an element. Numpy would
+    stack a list of equal-length cells into a 2-d array, so a list is
+    copied element by element."""
+    if isinstance(cells, np.ndarray) and cells.dtype == object:
+        return cells
+    return np.fromiter(cells, dtype=object, count=len(cells))
+
+
+def _unpack_cells(field, data, offsets, nulls):
+    """The receiving side of :meth:`EncodedImageColumn.__reduce_ex__`:
+    cells as zero-copy views over ``data`` (the received frame, or the
+    in-band bytes), which their numpy ``base`` chain keeps alive."""
+    buf = np.frombuffer(data, np.uint8)
+    offsets = np.frombuffer(offsets, np.int64)
+    cells = [buf[offsets[i]:offsets[i + 1]] for i in range(len(offsets) - 1)]
+    for i in nulls:
+        cells[i] = None
+    return EncodedImageColumn(field, cells)

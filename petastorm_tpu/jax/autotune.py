@@ -21,7 +21,9 @@ WINDOW_SEC``, and adjusts three knobs from the window stream:
 * **consumer-bound** verdict for 3 consecutive windows (the training
   step is the wall; the producer sits blocked on a full queue) →
   **shed decode threads**: the host's CPUs are not the problem, so hand
-  them back — one decoder thread at a time down to 1, via an in-process
+  them back — one decoder thread at a time down to 1 (or, where the
+  staging fill itself decodes, down to the width that keeps that fill
+  under half the consumer's interval between batches), via an in-process
   override of the ``PETASTORM_TPU_IMAGE_DECODER_THREADS`` parse
   (``codecs.set_image_decoder_threads_override``; never a mutation of
   ``os.environ``, which child processes inherit and later readers in
@@ -68,7 +70,7 @@ from petastorm_tpu.telemetry import (
 )
 from petastorm_tpu.telemetry.stall import CONSUMER_BOUND, PRODUCER_BOUND
 from petastorm_tpu.telemetry.timeseries import (
-    WindowedRollup, h2d_ready_share, io_wait_share,
+    WindowedRollup, decode_fused_share, h2d_ready_share, io_wait_share,
 )
 
 logger = logging.getLogger(__name__)
@@ -97,6 +99,12 @@ _readahead_owner = None
 #: pool-occupancy share at which the readahead depth sheds (memory
 #: pressure: the pool is the bound, not the wire)
 _READAHEAD_POOL_PRESSURE = 0.85
+
+#: the share of the consumer's interval between batches past which a
+#: shed would leave the staging fill's decode (``decode_fused``, one
+#: thread fewer stretching it by width / (width - 1)) too little slack:
+#: below it the fill hides behind the step with room for its jitter
+_FILL_SHARE_CEILING = 0.5
 
 #: ceiling on the autotuned ventilator in-flight extra (row-groups kept
 #: in flight beyond the pool's worker count; the knobless satellite of
@@ -228,6 +236,11 @@ class StagingAutotuner:
         self._h2d_streak = 0
         self._consumer_streak = 0
         self._producer_streak = 0
+        # the largest decode_fused share over the consumer-bound streak:
+        # the one window that closes after a consumer pause (the stager
+        # ticks only between puts) reads a diluted share, so a shed
+        # answers to the whole streak, not to its last window alone
+        self._streak_fill_share = 0.0
         # readahead-depth control (petastorm_tpu/readahead.py): deepen
         # while the fleet's io-wait share stays saturated, shed under
         # buffer-pool memory pressure
@@ -284,6 +297,9 @@ class StagingAutotuner:
                                  if verdict == CONSUMER_BOUND else 0)
         self._producer_streak = (self._producer_streak + 1
                                  if verdict == PRODUCER_BOUND else 0)
+        self._streak_fill_share = (
+            max(self._streak_fill_share, decode_fused_share(window))
+            if self._consumer_streak else 0.0)
         # readahead: the io-wait share only argues for more depth while
         # the consumer actually starves (producer-bound) — an io-heavy
         # but keeping-up pipeline gains nothing from deeper prefetch.
@@ -312,7 +328,8 @@ class StagingAutotuner:
             actions += self._deepen_readahead(io_share)
         if self._consumer_streak >= self._CONSECUTIVE:
             self._consumer_streak = 0
-            actions += self._shed_decode_threads()
+            actions += self._shed_decode_threads(self._streak_fill_share)
+            self._streak_fill_share = 0.0
             actions += self._lower_inflight()
         elif self._producer_streak >= self._CONSECUTIVE:
             self._producer_streak = 0
@@ -459,13 +476,24 @@ class StagingAutotuner:
             _override_owner = self
         return _override_owner is self
 
-    def _shed_decode_threads(self):
+    def _shed_decode_threads(self, fill_share=0.0):
         """Consumer-bound: the training step is the wall — hand decoder
-        CPUs back, one thread at a time down to 1."""
+        CPUs back, one thread at a time down to 1.
+
+        Where the staging fill decodes (``fill_share``, the largest
+        ``decode_fused`` seconds per second of the streak's windows, is
+        above 0) the decode sits on the staging thread's path to every
+        batch, so a shed stops at the width below which the fill's
+        seconds per delivered batch, stretched by ``current / (current -
+        1)``, would reach half the consumer's interval between
+        ``loader_next`` calls. Both are over the same batches, so the
+        ratio is ``fill_share`` itself."""
         if not self._owns_override():
             return []
         current = self._codecs.image_decoder_threads()
         if current <= 1:
+            return []
+        if fill_share * current / (current - 1) >= _FILL_SHARE_CEILING:
             return []
         self._thread_override = current - 1
         self._codecs.set_image_decoder_threads_override(

@@ -182,16 +182,15 @@ def make_jax_loader(dataset_url_or_urls, batch_size, mesh=None, data_axes=None,
         # Fused decode hand-shake (petastorm_tpu/fused.py): ask the
         # worker for still-encoded image cells whenever this loader's
         # batch path can decode them straight into its staging buffers —
-        # the no-row-shuffle noop re-batcher with the arena live. Other
-        # configurations (and per-field surprises like a dtype recast)
-        # fall back via the loader's own materialization, so requesting
-        # here is an optimization hint, never a correctness bet. Custom
-        # reader factories are left untouched — their signatures may not
-        # know the kwarg.
+        # unbucketed batches (row-shuffled or not) with the arena live.
+        # Other configurations (and per-field surprises like a dtype
+        # recast) fall back via the loader's own materialization, so
+        # requesting here is an optimization hint, never a correctness
+        # bet. Custom reader factories are left untouched — their
+        # signatures may not know the kwarg.
         reader_kwargs.setdefault(
             'defer_image_decode',
-            not shuffle_rows and bucket_boundaries is None
-            and staging.staging_enabled())
+            bucket_boundaries is None and staging.staging_enabled())
     reader = factory(dataset_url_or_urls, schema_fields=fields,
                      num_epochs=1 if inmemory_cache_all else num_epochs,
                      **reader_kwargs)
@@ -682,8 +681,9 @@ class JaxLoader:
                 # attach to the pull just folded in; no-op when untraced
                 with tracing.activate(self._last_pull_ctx, track='stager'):
                     # deferred image columns that THIS pass cannot fuse
-                    # (staging off, shuffled rows, dtype recast) decode
-                    # now, in one vectorized pass per column
+                    # (staging off, dtype recast) decode now, in one
+                    # vectorized pass per column; the others ride the
+                    # buffer still encoded
                     columns = self._materialize_encoded(columns)
                     with span('collate'):
                         # densify BEFORE the buffer: a variable field
@@ -854,10 +854,12 @@ class JaxLoader:
 
     def _materialize_encoded(self, columns):
         """Decode deferred image columns the CURRENT pass cannot fuse —
-        the staging arena is off, rows are shuffled (the random buffer
-        gathers decoded rows), batching is bucketed, or a ``dtypes=``
-        policy retargets the column's dtype (the fused fill writes the
-        codec's native dtype only). One vectorized ``materialize()`` per
+        the staging arena is off, batching is bucketed (rows are split
+        and padded per bucket), or a ``dtypes=`` policy retargets the
+        column's dtype (the fused fill writes the codec's native dtype
+        only). Shuffled rows fuse: the random buffer gathers encoded
+        cells, and the arena decodes the drawn rows into the slot. One
+        vectorized ``materialize()`` per
         column (native batch decoders, internal thread pool) — still the
         batched regime, just not fused into the destination; each decline
         is counted in ``petastorm_tpu_fused_decode_fallbacks_total`` so
@@ -868,8 +870,6 @@ class JaxLoader:
                 continue
             if self._stager is None:
                 reason = 'staging-off'
-            elif self._shuffle_rows:
-                reason = 'shuffled-rows'
             elif self._bucket_field is not None:
                 reason = 'bucketed'
             else:

@@ -135,6 +135,18 @@ def io_wait_share(window):
     return window['rates'].get(_IO_SECONDS_KEY, 0.0)
 
 
+_DECODE_FUSED_KEY = metric_key(STAGE_SECONDS, {'stage': 'decode_fused'})
+
+
+def decode_fused_share(window):
+    """Seconds-per-second one closed window spent in the staging fill's
+    ``decode_fused`` stage (the consumer decoding encoded cells straight
+    into arena slots; 0.0 where nothing fused) — the staging autotuner's
+    floor for shedding decoder threads (:mod:`petastorm_tpu.jax.
+    autotune`)."""
+    return window['rates'].get(_DECODE_FUSED_KEY, 0.0)
+
+
 # -- windowed rollup ----------------------------------------------------------
 
 

@@ -7,6 +7,9 @@ from petastorm_tpu.buffers import (
     BatchedNoopShufflingBuffer, BatchedRandomShufflingBuffer,
     NoopShufflingBuffer, RandomShufflingBuffer,
 )
+from petastorm_tpu.codecs import CompressedImageCodec
+from petastorm_tpu.fused import EncodedImageColumn
+from petastorm_tpu.unischema import UnischemaField
 
 
 class TestNoop:
@@ -184,6 +187,107 @@ class TestBatchedRandom:
                 assert len(arr) == rid + 1
             rows += len(b['id'])
         assert rows == 6
+
+
+_IMG = (4, 4, 3)
+_IMG_FIELD = UnischemaField('image', np.uint8, _IMG,
+                            CompressedImageCodec('png'), False)
+
+
+def _image_chunk(start, n, encoded):
+    """``n`` rows of ids and png images whose pixels encode the id; the
+    image column still encoded or decoded."""
+    import cv2
+    ids = np.arange(start, start + n)
+    images = np.stack([np.full(_IMG, i % 251, np.uint8) for i in ids])
+    if not encoded:
+        return {'id': ids, 'image': images}
+    cells = [np.frombuffer(cv2.imencode('.png', im)[1].tobytes(), np.uint8)
+             for im in images]
+    return {'id': ids, 'image': EncodedImageColumn(_IMG_FIELD, cells)}
+
+
+def _drain_shuffled(chunk_forms, seed=4):
+    """Batches of a random buffer fed one chunk per entry of
+    ``chunk_forms`` (True: image column encoded), encoded columns of the
+    batches decoded; plus the buffer."""
+    buf = BatchedRandomShufflingBuffer(
+        shuffling_buffer_capacity=24, min_after_retrieve=12, batch_size=6,
+        extra_capacity=12, seed=seed)
+    batches = []
+
+    def take():
+        batch = buf.retrieve()
+        image = batch['image']
+        if isinstance(image, EncodedImageColumn):
+            batch['image'] = image.materialize()
+        batches.append(batch)
+
+    for i, encoded in enumerate(chunk_forms):
+        buf.add_many(_image_chunk(10 * i, 10, encoded))
+        while buf.can_retrieve:
+            take()
+    buf.finish()
+    while buf.can_retrieve:
+        take()
+    return batches, buf
+
+
+class TestBatchedRandomEncoded:
+    """The fused-decode carrier in the random buffer: cells are gathered
+    in place of pixel rows, with the draws of the decoded column."""
+
+    def test_encoded_column_draws_the_rows_of_the_decoded_column(self):
+        forms = [True] * 7
+        encoded, buf = _drain_shuffled(forms)
+        decoded, _ = _drain_shuffled([False] * 7)
+        assert len(encoded) == len(decoded)
+        for a, b in zip(encoded, decoded):
+            np.testing.assert_array_equal(a['id'], b['id'])
+            np.testing.assert_array_equal(a['image'], b['image'])
+            np.testing.assert_array_equal(a['image'][:, 0, 0, 0],
+                                          a['id'] % 251)
+        assert sorted(np.concatenate([b['id'] for b in encoded]).tolist()) \
+            == list(range(70))
+        # drained: no slot still holds a cell (it would pin its frame)
+        assert all(c is None for c in buf._buffers['image'].cells)
+
+    def test_retrieve_hands_back_encoded_cells(self):
+        buf = BatchedRandomShufflingBuffer(
+            shuffling_buffer_capacity=10, min_after_retrieve=0,
+            batch_size=4, extra_capacity=10, seed=0)
+        buf.add_many(_image_chunk(0, 10, True))
+        batch = buf.retrieve()
+        assert isinstance(batch['image'], EncodedImageColumn)
+        assert batch['image'].shape == (4,) + _IMG
+        assert batch['image'].field is _IMG_FIELD
+        # the cells left behind the live rows are cleared
+        assert all(c is None for c in buf._buffers['image'].cells[buf.size:])
+
+    @pytest.mark.parametrize('forms', [(True, False, True, True),
+                                       (False, True, False, True)])
+    def test_mixed_chunks_keep_the_decoded_draws(self, forms):
+        """A row-group the worker decoded (or one it deferred after a
+        decoded one) joins the buffer without changing a draw, and each
+        decode the mix forces is counted as a fused-decode decline."""
+        from petastorm_tpu import telemetry as T
+        from petastorm_tpu.fused import FUSED_FALLBACKS
+        T.reset_for_tests()
+        try:
+            mixed, _ = _drain_shuffled(forms)
+            declines = T.get_registry().counter_value(
+                FUSED_FALLBACKS, reason='mixed-chunks')
+        finally:
+            T.reset_for_tests()
+        # the first decoded chunk after encoded ones turns the column
+        # decoded; every encoded chunk after that decodes on its add
+        first = forms.index(False)
+        assert declines == (first > 0) + sum(forms[first + 1:])
+        decoded, _ = _drain_shuffled([False] * len(forms))
+        assert len(mixed) == len(decoded)
+        for a, b in zip(mixed, decoded):
+            np.testing.assert_array_equal(a['id'], b['id'])
+            np.testing.assert_array_equal(a['image'], b['image'])
 
 
 class TestRandomizedOpSequences:

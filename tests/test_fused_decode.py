@@ -220,14 +220,64 @@ def test_encoded_column_surface_and_slicing():
     np.testing.assert_array_equal(column.materialize(), np.stack(images))
 
 
+def test_encoded_column_gathers_and_assigns_cells():
+    """The random shuffling buffer's surface: ``empty`` storage, slot
+    assignment from a list-backed column (equal-length cells are not
+    stacked), a gather by index array, and slots emptied with None."""
+    _, field = _png_codec_field()
+    cells, images = _png_cells(4, seed=8)
+    same = [cells[0]] * 3       # equal lengths: numpy would stack these
+    store = EncodedImageColumn(field, cells).empty(8)
+    assert store.shape == (8,) + IMG_SHAPE
+    store[0:4] = EncodedImageColumn(field, cells)
+    store[4:7] = EncodedImageColumn(field, same)
+    picked = store[np.array([3, 0, 5])]
+    assert isinstance(picked, EncodedImageColumn) and len(picked) == 3
+    np.testing.assert_array_equal(
+        picked.materialize(), np.stack([images[3], images[0], images[0]]))
+    store[np.array([1])] = store[np.array([6])]
+    assert store.cells[1] is store.cells[6]
+    store[4:8] = None
+    assert all(c is None for c in store.cells[4:])
+    with pytest.raises(TypeError, match='encoded'):
+        store[np.int64(0)]
+
+
 def test_encoded_column_pickles_to_owned_cells():
     _, field = _png_codec_field()
     cells, images = _png_cells(3, seed=7)
     views = [np.frombuffer(c, np.uint8) for c in cells]
-    column = EncodedImageColumn(field, views, owner=object())
+    column = EncodedImageColumn(field, views)
     clone = pickle.loads(pickle.dumps(column))
-    assert clone.owner is None
+    # the clone's cells view its own copy of the payload
+    assert not any(np.shares_memory(c, v) for c in clone.cells
+                   for v in views)
     np.testing.assert_array_equal(clone.materialize(), np.stack(images))
+
+
+def test_encoded_column_crosses_the_pool_as_one_frame():
+    """serialize_frames ships the cells as ONE out-of-band frame (not a
+    bytes object per cell in the pickle stream), and the receiver's
+    cells are zero-copy views over that frame."""
+    from petastorm_tpu.serializers import PickleSerializer
+    _, field = _png_codec_field()
+    cells, images = _png_cells(5, seed=9)
+    column = EncodedImageColumn(field, [np.frombuffer(c, np.uint8)
+                                        for c in cells])
+    serializer = PickleSerializer()
+    frames = serializer.serialize_frames({'image': column})
+    assert len(frames) == 2
+    assert memoryview(frames[1]).nbytes == sum(len(c) for c in cells)
+    assert memoryview(frames[0]).nbytes < min(len(c) for c in cells)
+    received = bytearray(frames[1])     # what the channel hands over
+    clone = serializer.deserialize_frames([frames[0], received])['image']
+    frame = np.frombuffer(received, np.uint8)
+    assert all(np.shares_memory(c, frame) for c in clone.cells)
+    assert [bytes(c) for c in clone.cells] == cells
+    np.testing.assert_array_equal(clone.materialize(), np.stack(images))
+    # the one-frame contract (service pool) carries it in band
+    inband = serializer.deserialize(serializer.serialize(column))
+    assert [bytes(c) for c in inband.cells] == cells
 
 
 # -- worker deferral gates ----------------------------------------------------
@@ -323,15 +373,40 @@ def test_fused_pad_tail_zero_fills_and_masks(image_dataset):
     _assert_same(batches, oracle)
 
 
+def _assert_same_batches(batches_a, batches_b):
+    assert len(batches_a) == len(batches_b)
+    for a, b in zip(batches_a, batches_b):
+        assert sorted(a) == sorted(b)
+        for name in a:
+            np.testing.assert_array_equal(a[name], b[name])
+
+
+def _assert_shuffled_rows_fuse(url, pool):
+    """Shuffled rows, fused: the random buffer gathers encoded cells and
+    the arena decodes the drawn rows into its buffers. One worker fixes
+    the row-group order, so one seed must give the oracle's batches
+    exactly — the same rows, in the same batches, in the same order."""
+    kw = dict(batch_size=24, shuffle_rows=True, seed=3, workers_count=1,
+              reader_pool_type=pool)
+    batches, diag = _collect(url, **kw)
+    assert diag['fused_decode_mode'] in ('fused-into-slot',
+                                         'fused-into-slab')
+    assert diag['fused_decode_rows'] == 96
+    assert 'fused_decode_fallback' not in diag
+    with _env(PETASTORM_TPU_STAGING='0', PETASTORM_TPU_NATIVE='0'):
+        oracle, oracle_diag = _collect(url, **kw)
+    assert oracle_diag['fused_decode_mode'] == 'batched'
+    _assert_same_batches(batches, oracle)
+
+
 def test_shuffled_rows_fall_back_and_match_decoded_path(image_dataset):
     url, _ = image_dataset
-    kw = dict(batch_size=24, shuffle_rows=True, seed=3)
-    batches, diag = _collect(url, **kw)
-    assert diag['fused_decode_mode'] == 'batched'
-    with _env(PETASTORM_TPU_STAGING='0', PETASTORM_TPU_NATIVE='0'):
-        oracle, _ = _collect(url, **kw)
-    # same seed, same buffer discipline: the same rows, batched alike
-    _assert_same(batches, oracle)
+    _assert_shuffled_rows_fuse(url, 'thread')
+
+
+def test_shuffled_rows_fuse_across_the_process_pool(image_dataset):
+    url, _ = image_dataset
+    _assert_shuffled_rows_fuse(url, 'process')
 
 
 def test_dtype_cast_materializes_and_matches(image_dataset):

@@ -540,6 +540,49 @@ def test_autotune_sheds_and_restores_decode_threads():
         assert codecs.image_decoder_threads() == 3
 
 
+@pytest.mark.parametrize('fill_at_4,floor', [(0.0, 1), (0.1, 1), (0.2, 2),
+                                              (0.3, 3), (0.4, 4)])
+def test_autotune_shed_stops_at_the_decode_fill_floor(fill_at_4, floor):
+    """Where the staging fill decodes, a shed stops at the width below
+    which the fill (its share of the window growing as width / new
+    width) would take half the consumer's interval between batches;
+    with no ``decode_fused`` in the window it sheds to 1 as before."""
+    fill_key = metric_key(STAGE_SECONDS, {'stage': 'decode_fused'})
+    with _env(PETASTORM_TPU_IMAGE_DECODER_THREADS='4'):
+        tuner, _, _ = _tuner()
+        for _ in range(3 * 5):
+            window = _window(verdict=T.CONSUMER_BOUND)
+            width = codecs.image_decoder_threads()
+            if fill_at_4:
+                window['rates'][fill_key] = fill_at_4 * 4 / width
+            tuner.observe(window)
+        assert codecs.image_decoder_threads() == floor
+        assert tuner.decisions == 4 - floor
+        tuner.close()
+
+
+def test_autotune_shed_floor_holds_over_a_diluted_window():
+    """The window that closes after a consumer pause reads a diluted
+    ``decode_fused`` share; the floor answers to the streak's largest."""
+    fill_key = metric_key(STAGE_SECONDS, {'stage': 'decode_fused'})
+    with _env(PETASTORM_TPU_IMAGE_DECODER_THREADS='2'):
+        tuner, _, _ = _tuner()
+        for _ in range(4):
+            for share in (0.4, 0.4, 0.01):
+                window = _window(verdict=T.CONSUMER_BOUND)
+                window['rates'][fill_key] = share
+                assert tuner.observe(window) == []
+        assert codecs.image_decoder_threads() == 2
+        # a streak whose every window leaves room sheds
+        for _ in range(3):
+            window = _window(verdict=T.CONSUMER_BOUND)
+            window['rates'][fill_key] = 0.2
+            actions = tuner.observe(window)
+        assert [a['action'] for a in actions] == ['shed_decode_threads']
+        assert codecs.image_decoder_threads() == 1
+        tuner.close()
+
+
 def test_autotune_thread_override_is_single_owner():
     """Two live tuners in one process: the thread override is one slot —
     the second tuner neither sheds over the first's setting nor wipes it
