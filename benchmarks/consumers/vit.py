@@ -6,6 +6,8 @@ A configuration names this file by ``"consumer": "vit"``; its dataset
 delivers ``image`` (uint8 NHWC) and ``label`` columns.
 """
 
+import copy
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,6 +59,31 @@ def init_params(seed, config, sharding):
                 'head': normal((d, w['n_classes']), d ** -0.5)}
 
     return jax.jit(make, out_shardings=sharding)(_key(seed))
+
+
+def check_config(config):
+    """Raise ``ValueError`` where ``config`` is not one this consumer can
+    run: patches that do not tile the image, or images that are not RGB."""
+    w = config['widths']
+    if w['image_size'] % w['patch_size']:
+        raise ValueError('patch_size %d does not tile image_size %d'
+                         % (w['patch_size'], w['image_size']))
+    if w['channels'] != 3:
+        raise ValueError('the normalize takes RGB images, not %d channels'
+                         % w['channels'])
+
+
+def tiny(config):
+    """``config`` cut to a size a CPU test run holds: a two-layer ViT of
+    width 32 over 32px images in 8px patches, eight rows a chip, the plain
+    normalize formula, and the reference four rows at a time."""
+    config = copy.deepcopy(config)
+    config['widths'].update(image_size=32, patch_size=8, d_model=32,
+                            n_heads=2, n_layers=2, d_ff=64)
+    config['batch_per_chip'] = 8
+    config['normalize']['impl'] = 'jnp'
+    config['reference_chunk'] = 4
+    return config
 
 
 def model_config(widths):

@@ -54,6 +54,13 @@ def _rowgroup(task):
     return ids, labels, encoded
 
 
+def tiny(dataset):
+    """``dataset`` cut to a size a CPU test run holds: 256 rows of 32px
+    images, 16 to a row-group, in four files."""
+    return dict(dataset, rows=256, rowgroup_rows=16, files=4,
+                image_shape=[32, 32, 3])
+
+
 def generate(directory, seed, dataset, workers):
     """Write the dataset that ``dataset`` (the configuration's ``dataset``
     group) describes under ``directory`` and return its URL.

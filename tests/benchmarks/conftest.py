@@ -15,17 +15,16 @@ if BENCH_DIR not in sys.path:
 
 
 def tiny(cell):
-    """``cell`` cut to a size a test run holds: 32px images, a two-layer
-    ViT of width 32, eight rows a chip, the plain normalize formula."""
+    """``cell`` cut to a size a test run holds, by the ``tiny`` of its
+    dataset's file and of its consumer's: each configuration's files bring
+    their own cut."""
+    from lib import harness
     cell = copy.deepcopy(cell)
     config = cell['config']
-    config['dataset'].update(rows=256, rowgroup_rows=16, files=4,
-                             image_shape=[32, 32, 3])
-    config['widths'].update(image_size=32, patch_size=8, d_model=32,
-                            n_heads=2, n_layers=2, d_ff=64)
-    config['batch_per_chip'] = 8
-    config['normalize']['impl'] = 'jnp'
-    config['reference_chunk'] = 4
+    dataset = harness.load_module('datasets', config['dataset']['kind'])
+    config['dataset'] = dataset.tiny(config['dataset'])
+    cell['config'] = harness.load_module(
+        'consumers', config['consumer']).tiny(config)
     return tiny_traffic(cell)
 
 

@@ -213,3 +213,40 @@ def test_device_readers_raise_where_no_program_ran_on_a_chip(metric):
             'normalize_roofline': 100 * (128 * 224 * 224 * 3 * 3 / 819e9)
             / 50e-6}[metric]
     assert value == pytest.approx(want, rel=1e-3)
+
+
+@pytest.mark.parametrize('metric', ['vit_step_mfu', 'normalize_roofline'])
+def test_device_readers_read_a_share_a_chip_on_four_chips(metric):
+    """Each chip runs its own copy of a program on its ``batch_per_chip``
+    rows: four planes of one run each read the one-plane share, not four
+    times it."""
+    import types
+
+    from lib import harness, trace
+    vit = harness.load_module('consumers', 'vit')
+    reader = harness.load_module('metrics', metric)
+    config = harness.resolve('vit_s16.shuffled')['config']
+    runs = [('jit_bench_normalize_mesh(7)', 0, 100_000),
+            ('jit_step(9)', 0, 40_000_000)]
+
+    def read(planes):
+        found = trace.Reduction({}, [], 0, 100, {
+            '/device:TPU:%d' % i: list(runs) for i in range(planes)})
+        return reader.read(types.SimpleNamespace(
+            trace=types.SimpleNamespace(reduction=found),
+            peaks=(197e12, 819e9), consumer=vit, config=config,
+            flops_per_row=vit.flops_per_row(config), chips=planes))
+
+    assert read(4) == pytest.approx(read(1), rel=1e-9)
+    assert read(4) < 100
+
+
+def test_the_vit_consumer_refuses_a_grid_it_cannot_run():
+    from lib import harness
+    vit = harness.load_module('consumers', 'vit')
+    config = harness.resolve('vit_s16.shuffled')['config']
+    vit.check_config(config)
+    for key, value in (('patch_size', 15), ('channels', 1)):
+        bad = dict(config, widths=dict(config['widths'], **{key: value}))
+        with pytest.raises(ValueError):
+            vit.check_config(bad)
