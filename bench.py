@@ -1821,13 +1821,12 @@ def main():
 
     def sec_selective_read():
         """Query-shaped reads (ISSUE 12): a range predicate at ~1%/10%/50%
-        selectivity over the sorted-id jpeg dataset, pruned+late-
-        materialized vs the decode-everything-then-filter oracle
-        (PETASTORM_TPU_PUSHDOWN=0). The rate is the EFFECTIVE scan rate —
-        dataset rows / epoch wall — because a selective read's value is
-        how fast it disposes of the rows it does NOT want; rowgroups
-        pruned is recorded so the speedup is attributable to pruning,
-        not caching."""
+        selectivity over the sorted-id jpeg dataset, through the
+        pruned + late-materialized read. The rate is the EFFECTIVE scan
+        rate — dataset rows / epoch wall — because a selective read's
+        value is how fast it disposes of the rows it does NOT want;
+        rowgroups pruned is recorded so the rate is attributable to
+        pruning, not caching."""
         from petastorm_tpu import pushdown
         from petastorm_tpu.filters import FiltersPredicate
         from petastorm_tpu.reader import make_batch_reader
@@ -1835,31 +1834,15 @@ def main():
 
         _build_selective(selective_url)
 
-        # rung -> knob overrides: full fast path / late materialization
-        # without plan-time pruning (attribution) / the
-        # decode-everything-then-filter full-scan oracle
-        modes = {'pruned': {},
-                 'late_only': {'PETASTORM_TPU_PUSHDOWN_PRUNE': '0'},
-                 'unpruned': {'PETASTORM_TPU_PUSHDOWN': '0'}}
-
-        def one_epoch(cutoff, mode):
-            saved = {k: os.environ.get(k) for k in modes[mode]}
-            os.environ.update(modes[mode])
-            try:
-                start = time.monotonic()
-                with make_batch_reader(
-                        selective_url, reader_pool_type='thread',
-                        shuffle_row_groups=False,
-                        predicate=FiltersPredicate(
-                            [('id', '<', cutoff)])) as reader:
-                    delivered = sum(len(b.id) for b in reader)
-                return time.monotonic() - start, delivered
-            finally:
-                for k, v in saved.items():
-                    if v is None:
-                        os.environ.pop(k, None)
-                    else:
-                        os.environ[k] = v
+        def one_epoch(cutoff):
+            start = time.monotonic()
+            with make_batch_reader(
+                    selective_url, reader_pool_type='thread',
+                    shuffle_row_groups=False,
+                    predicate=FiltersPredicate(
+                        [('id', '<', cutoff)])) as reader:
+                delivered = sum(len(b.id) for b in reader)
+            return time.monotonic() - start, delivered
 
         registry = get_registry()
         pruned_before = registry.counter_value(pushdown.ROWGROUPS_PRUNED)
@@ -1867,24 +1850,17 @@ def main():
         for label, fraction in (('1pct', 0.01), ('10pct', 0.10),
                                 ('50pct', 0.50)):
             cutoff = max(1, int(SELECTIVE_ROWS * fraction))
-            # warm the page cache + footer memo so every rung compares
-            # steady-state read paths, not first-touch I/O
-            one_epoch(cutoff, 'pruned')
-            seconds = {}
-            for mode in modes:
-                seconds[mode], delivered = one_epoch(cutoff, mode)
-                assert delivered == cutoff, (mode, delivered, cutoff)
-                key = ('selective_read_%s_rows_per_sec' % label
-                       if mode == 'pruned'
-                       else 'selective_read_%s_%s_rows_per_sec'
-                       % (label, mode))
-                extra[key] = round(SELECTIVE_ROWS / seconds[mode], 1)
-            extra['selective_read_%s_speedup' % label] = \
-                round(seconds['unpruned'] / seconds['pruned'], 3)
+            # warm the page cache + footer memo so the timed epoch reads
+            # the steady-state path, not first-touch I/O
+            one_epoch(cutoff)
+            seconds, delivered = one_epoch(cutoff)
+            assert delivered == cutoff, (delivered, cutoff)
+            extra['selective_read_%s_rows_per_sec' % label] = round(
+                SELECTIVE_ROWS / seconds, 1)
         extra['selective_read_rowgroups_pruned'] = int(
             registry.counter_value(pushdown.ROWGROUPS_PRUNED)
             - pruned_before)
-        # delta over this section only (warm-ups + all rungs), like the
+        # delta over this section only (warm-ups included), like the
         # pruned count — the absolute counter would absorb any earlier
         # predicate reader in the process
         extra['selective_read_late_materialized_rows'] = int(

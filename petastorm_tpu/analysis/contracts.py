@@ -19,10 +19,10 @@ telemetry back).
 #: ``ventilate`` hand item to pool · ``io`` parquet row-group read ·
 #: ``decode`` codec decode · ``filter`` predicate/row-mask eval ·
 #: ``transform`` TransformSpec · ``queue_wait`` consumer blocked pulling ·
-#: ``collate`` re-batch/shuffle-buffer/densify · ``h2d`` host→device
-#: staging (pre-arena path) · ``h2d_ready`` staging arena blocked until a
-#: slot's previous transfer completed · ``stage_fill`` cast/pad/mask copy
-#: into the arena slot · ``h2d_dispatch`` async transfer dispatch ·
+#: ``collate`` re-batch/shuffle-buffer/densify · ``h2d_ready`` staging
+#: arena blocked until a slot's previous transfer completed ·
+#: ``stage_fill`` cast/pad/mask copy into the arena slot ·
+#: ``h2d_dispatch`` async transfer dispatch ·
 #: ``cache_hit_read`` decoded-row-group cache hit served (mmap + column
 #: reconstruct) · ``cache_fill`` decoded batch serialized to Arrow IPC +
 #: atomically published into the cache · ``decode_fused`` deferred image
@@ -60,7 +60,7 @@ telemetry back).
 #: producer wait, not this) · ``deserialize`` the consumer's receive and
 #: pickle-5 rebuild of one process-pool message
 STAGES = ('ventilate', 'io', 'decode', 'filter', 'transform', 'queue_wait',
-          'collate', 'h2d', 'h2d_ready', 'stage_fill', 'h2d_dispatch',
+          'collate', 'h2d_ready', 'stage_fill', 'h2d_dispatch',
           'cache_hit_read', 'cache_fill', 'decode_fused',
           'rowgroup_prune', 'late_materialize', 'autotune',
           'readahead_fetch', 'pack', 'encode', 'write_flush', 'compact',
@@ -259,7 +259,6 @@ KNOWN_KNOBS = frozenset([
     'PETASTORM_TPU_TRACE_SAMPLE',
     'PETASTORM_TPU_TRACE_DUMP',
     'PETASTORM_TPU_TRACE_AUTODUMP_WINDOWS',
-    'PETASTORM_TPU_STAGING',
     'PETASTORM_TPU_STAGING_SLOTS',
     'PETASTORM_TPU_STAGING_AUTOTUNE',
     'PETASTORM_TPU_STAGING_AUTOTUNE_WINDOW_SEC',
@@ -294,8 +293,6 @@ KNOWN_KNOBS = frozenset([
     'PETASTORM_TPU_SERVICE_JOB_WEIGHT',
     'PETASTORM_TPU_SERVICE_JOB_PRIORITY',
     'PETASTORM_TPU_SERVICE_PLACEMENT',
-    'PETASTORM_TPU_PUSHDOWN',
-    'PETASTORM_TPU_PUSHDOWN_PRUNE',
     'PETASTORM_TPU_PUSHDOWN_WORKERS',
     'PETASTORM_TPU_READAHEAD',
     'PETASTORM_TPU_READAHEAD_DEPTH',

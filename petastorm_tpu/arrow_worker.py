@@ -180,10 +180,6 @@ class RowGroupWorker(WorkerBase):
         # reader shipped no plan (caching readers — a warm epoch must
         # not prefetch bytes it will never read)
         self._readahead = readahead.attach(args)
-        # PETASTORM_TPU_PUSHDOWN=0: the decode-everything-then-filter
-        # oracle shape (exact-parity baseline + the bench's full-scan
-        # rung) — resolved once per worker, in the worker's own process
-        self._fullscan_oracle = pushdown.fullscan_oracle()
         # decoded-cache key identity, resolved lazily (per process, per
         # parquet file) — see _decoded_fingerprint
         self._decode_fp = None
@@ -332,11 +328,6 @@ class RowGroupWorker(WorkerBase):
 
     def _load_rowgroup(self, piece, worker_predicate, drop_partition,
                        item_index=None, epoch=None):
-        if self._fullscan_oracle and worker_predicate is not None:
-            return self._load_rowgroup_fullscan(piece, worker_predicate,
-                                                drop_partition,
-                                                item_index=item_index,
-                                                epoch=epoch)
         needed = self._needed_stored_fields()
         partition_keys = [k for k in piece.partition_values if k in needed]
         file_columns = [n for n in needed if n not in piece.partition_values]
@@ -469,89 +460,6 @@ class RowGroupWorker(WorkerBase):
             with span('transform'):
                 batch = self._apply_transform(batch)
         return batch
-
-    def _load_rowgroup_fullscan(self, piece, worker_predicate,
-                                drop_partition, item_index=None,
-                                epoch=None):
-        """The decode-everything-then-filter ORACLE
-        (``PETASTORM_TPU_PUSHDOWN=0``): one read of every needed +
-        predicate column, every row of every column decoded, the
-        predicate evaluated over the fully-decoded columns, survivors
-        sliced out after the fact. The exact-parity comparison baseline
-        and the bench ``selective_read`` section's full-scan-priced
-        rung — never the production path (the default is the two-phase
-        late-materializing read above)."""
-        needed = self._needed_stored_fields()
-        partition_keys = [k for k in piece.partition_values if k in needed]
-        file_columns = [n for n in needed if n not in piece.partition_values]
-        pred_fields = sorted(worker_predicate.get_fields())
-        missing = [f for f in pred_fields
-                   if f not in self._stored_schema.fields
-                   and f not in piece.partition_values]
-        if missing:
-            raise ValueError('Predicate references unknown fields: %s'
-                             % missing)
-        pred_file_fields = [f for f in pred_fields
-                            if f not in piece.partition_values]
-        read_columns = list(dict.fromkeys(file_columns + pred_file_fields))
-
-        if self._readahead is not None:
-            # the oracle's one read wants everything at once, so the
-            # whole union is the prefetchable set here
-            self._readahead.observe(item_index, epoch, read_columns)
-        pf = self._parquet_file(piece.path)
-        table = self._read_columns(pf, piece, read_columns)
-        num_rows = table.num_rows
-        if faults.ARMED:
-            # see the sibling site above: armed-only decode span so
-            # injected delays read as decode time in the critical path
-            with span('decode'):
-                faults.fault_hit('decode.rowgroup', key='%s#rg%d'
-                                 % (piece.path, piece.row_group))
-        with span('decode'):
-            decoded = {name: self._decode_column(name, table.column(name))
-                       for name in read_columns}
-
-        values = {}
-        for name in pred_fields:
-            if name in piece.partition_values:
-                field = self._stored_schema.fields.get(name)
-                value = self._typed_partition_value(
-                    field, piece.partition_values[name])
-                values[name] = np.full(num_rows, value, dtype=object)
-            else:
-                values[name] = decoded[name]
-        with span('filter'):
-            mask = worker_predicate.do_include_batch(values)
-            if mask is None:
-                mask = np.fromiter(
-                    (worker_predicate.do_include(
-                        {f: values[f][i] for f in pred_fields})
-                     for i in range(num_rows)), dtype=bool, count=num_rows)
-            mask = np.asarray(mask, dtype=bool)
-            if mask.shape != (num_rows,):
-                # same fail-loud contract as _predicate_mask: a
-                # malformed predicate must not silently mis-select in
-                # the ORACLE either — a parity mismatch would otherwise
-                # be blamed on the fast path
-                raise ValueError(
-                    'Predicate %s.do_include_batch returned mask of '
-                    'shape %s for %d rows'
-                    % (type(worker_predicate).__name__, mask.shape,
-                       num_rows))
-        row_indices = np.flatnonzero(mask)
-
-        overlap = self._ngram.length - 1 if self._ngram is not None else 0
-        row_indices = self._apply_row_drop(row_indices, drop_partition,
-                                           overlap)
-        if row_indices.size == 0:
-            return None
-        select_all = row_indices.size == num_rows
-        columns = {name: (decoded[name] if select_all
-                          else decoded[name][row_indices])
-                   for name in file_columns}
-        return self._finish_batch(columns, piece, partition_keys,
-                                  row_indices.size)
 
     def _predicate_mask(self, pf, piece, predicate):
         """Two-phase read: evaluate the predicate on its own columns first

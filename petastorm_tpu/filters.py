@@ -193,8 +193,7 @@ def prune_row_group_indices(dataset_info, pieces, piece_indices, clauses,
     (:func:`petastorm_tpu.pushdown.plan_rowgroup_pruning` — one footer
     read per file, in parallel, memoized process-wide) then runs over
     the survivors, and only when a filtered column actually lives in the
-    files. ``PETASTORM_TPU_PUSHDOWN=0`` limits pruning to the
-    partition-value pass (the statistics-pruning oracle escape hatch).
+    files.
     """
     from petastorm_tpu.arrow_worker import typed_partition_value
 
@@ -224,8 +223,6 @@ def prune_row_group_indices(dataset_info, pieces, piece_indices, clauses,
     # pass 2: footer statistics for the survivors, through the memoized
     # planner (lazy import: pushdown imports this module at its top)
     from petastorm_tpu import pushdown
-    if not pushdown.pushdown_enabled():
-        return survivors
     plan = pushdown.plan_rowgroup_pruning(dataset_info, pieces, survivors,
                                           clauses=clauses,
                                           stored_schema=stored_schema)

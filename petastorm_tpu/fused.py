@@ -42,8 +42,8 @@ classic worker-side batched decode, counted in
 "decode is batched but not fused" runbook in docs/troubleshoot.md reads
 these): the worker defers only fixed-shape, numeric, null-free image
 columns on the no-transform/no-ngram/no-cache path; the loader
-materializes early when staging is off, batching is bucketed, or a
-dtype cast retargets the column; the random buffer decodes a column
+materializes early when batching is bucketed or a dtype cast retargets
+the column; the random buffer decodes a column
 whose chunks arrive some encoded and some decoded (``mixed-chunks``).
 
 Ownership contract (pipesan): ``EncodedImageColumn.cells`` hold ZERO-COPY
@@ -194,8 +194,8 @@ class EncodedImageColumn:
 
     def materialize(self):
         """Decode to a fresh page-aligned owned batch — the fallback for
-        consumers that cannot provide a destination (staging disabled,
-        bucketed batching, dtype recast)."""
+        consumers that cannot provide a destination (bucketed batching,
+        dtype recast)."""
         out = alloc_column_slab(self.shape, self.dtype)
         return self.decode_into(out)
 

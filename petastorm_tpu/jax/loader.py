@@ -182,15 +182,14 @@ def make_jax_loader(dataset_url_or_urls, batch_size, mesh=None, data_axes=None,
         # Fused decode hand-shake (petastorm_tpu/fused.py): ask the
         # worker for still-encoded image cells whenever this loader's
         # batch path can decode them straight into its staging buffers —
-        # unbucketed batches (row-shuffled or not) with the arena live.
+        # unbucketed batches, row-shuffled or not.
         # Other configurations (and per-field surprises like a dtype
         # recast) fall back via the loader's own materialization, so
         # requesting here is an optimization hint, never a correctness
         # bet. Custom reader factories are left untouched — their
         # signatures may not know the kwarg.
-        reader_kwargs.setdefault(
-            'defer_image_decode',
-            bucket_boundaries is None and staging.staging_enabled())
+        reader_kwargs.setdefault('defer_image_decode',
+                                 bucket_boundaries is None)
     reader = factory(dataset_url_or_urls, schema_fields=fields,
                      num_epochs=1 if inmemory_cache_all else num_epochs,
                      **reader_kwargs)
@@ -271,10 +270,12 @@ class JaxLoader:
         # per-batch-length sharded row plans (see _shard_plan_for); None
         # values pin the make_array_from_process_local_data fallback
         self._shard_plans = {}
-        self._stager = None   # per-pass staging arena (stage thread only)
+        # per-pass staging arena (stage thread only); __iter__ makes a
+        # fresh one for every pass
+        self._stager = self._new_stager()
         # staging autotuner (jax/autotune.py): created lazily at the
-        # first pass when staging is on and the knob allows, kept across
-        # passes so its learned settings and decision log survive replays
+        # first pass when the knob allows, kept across passes so its
+        # learned settings and decision log survive replays
         self._autotuner = None
         self._stage_thread = None
         self._out_queue = None
@@ -303,8 +304,8 @@ class JaxLoader:
         self._uses_provenance = hasattr(reader, 'next_batch_info')
         # trace context of the most recent reader pull (staging thread
         # only): batches mix rows across pulls, so staging-side trace
-        # events (collate/h2d) attribute to the pull being folded in —
-        # the honest approximation for a batching stage
+        # events (collate, stage_fill, h2d_*) attribute to the pull being
+        # folded in — the honest approximation for a batching stage
         self._last_pull_ctx = None
         # staging gauges (see diagnostics): who is waiting on whom? The
         # seconds of this loader's loader_next and stage_backpressure spans
@@ -465,23 +466,24 @@ class JaxLoader:
         # replay must not inherit slots whose in-flight transfers
         # belonged to the previous pass, and knob changes (after
         # staging.refresh_staging()) take effect here
-        self._stager = staging.make_stager(
-            self._batch_size, self._dtypes, self._last_batch,
-            self._put_to_device)
-        if self._stager is not None:
-            from petastorm_tpu.jax import autotune
-            if self._autotuner is None and autotune.autotune_enabled():
-                self._autotuner = autotune.StagingAutotuner(self)
-            if self._autotuner is not None:
-                # the new pass's stager starts at the depth the tuner
-                # already learned (decisions survive epoch replays)
-                self._autotuner.apply_learned(self._stager)
+        self._stager = self._new_stager()
+        from petastorm_tpu.jax import autotune
+        if self._autotuner is None and autotune.autotune_enabled():
+            self._autotuner = autotune.StagingAutotuner(self)
+        if self._autotuner is not None:
+            # the new pass's stager starts at the depth the tuner
+            # already learned (decisions survive epoch replays)
+            self._autotuner.apply_learned(self._stager)
         self._out_queue = queue.Queue(maxsize=self._prefetch)
         self._stage_thread = threading.Thread(target=self._stage_loop,
                                               daemon=True,
                                               name='petastorm-tpu-stager')
         self._stage_thread.start()
         return self
+
+    def _new_stager(self):
+        return staging.make_stager(self._batch_size, self._dtypes,
+                                   self._last_batch, self._put_to_device)
 
     def __next__(self):
         if self._out_queue is None:
@@ -677,13 +679,12 @@ class JaxLoader:
                 return
             buf = self._make_buffer()
             for columns in self._pull_batches():
-                # staging-side trace events (collate/h2d spans below)
+                # staging-side trace events (collate, stage_fill, h2d_*)
                 # attach to the pull just folded in; no-op when untraced
                 with tracing.activate(self._last_pull_ctx, track='stager'):
                     # deferred image columns that THIS pass cannot fuse
-                    # (staging off, dtype recast) decode now, in one
-                    # vectorized pass per column; the others ride the
-                    # buffer still encoded
+                    # (dtype recast) decode now, in one vectorized pass
+                    # per column; the others ride the buffer still encoded
                     columns = self._materialize_encoded(columns)
                     with span('collate'):
                         # densify BEFORE the buffer: a variable field
@@ -710,12 +711,10 @@ class JaxLoader:
         except Exception as e:  # noqa: BLE001 - surfaced to consumer
             self._stage_error = e
         finally:
-            if self._stager is not None:
-                # drop slot slabs + their in-flight device refs: a ring
-                # kept across the epoch gap would pin host and device
-                # memory the post-epoch consumer (eval, checkpointing)
-                # may need
-                self._stager.release()
+            # drop slot slabs + their in-flight device refs: a ring kept
+            # across the epoch gap would pin host and device memory the
+            # post-epoch consumer (eval, checkpointing) may need
+            self._stager.release()
             # set happens-before put: a sentinel can only be OBSERVED in
             # the queue after _produce_done is visible, which is what lets
             # __iter__'s probe distinguish "real mid-pass batches" from "a
@@ -854,12 +853,11 @@ class JaxLoader:
 
     def _materialize_encoded(self, columns):
         """Decode deferred image columns the CURRENT pass cannot fuse —
-        the staging arena is off, batching is bucketed (rows are split
-        and padded per bucket), or a ``dtypes=`` policy retargets the
-        column's dtype (the fused fill writes the codec's native dtype
-        only). Shuffled rows fuse: the random buffer gathers encoded
-        cells, and the arena decodes the drawn rows into the slot. One
-        vectorized ``materialize()`` per
+        batching is bucketed (rows are split and padded per bucket), or a
+        ``dtypes=`` policy retargets the column's dtype (the fused fill
+        writes the codec's native dtype only). Shuffled rows fuse: the
+        random buffer gathers encoded cells, and the arena decodes the
+        drawn rows into the slot. One vectorized ``materialize()`` per
         column (native batch decoders, internal thread pool) — still the
         batched regime, just not fused into the destination; each decline
         is counted in ``petastorm_tpu_fused_decode_fallbacks_total`` so
@@ -868,9 +866,7 @@ class JaxLoader:
         for name, column in columns.items():
             if not isinstance(column, EncodedImageColumn):
                 continue
-            if self._stager is None:
-                reason = 'staging-off'
-            elif self._bucket_field is not None:
+            if self._bucket_field is not None:
                 reason = 'bucketed'
             else:
                 want = self._dtypes.get(name)
@@ -886,13 +882,13 @@ class JaxLoader:
         return out if out is not None else columns
 
     def _retrieve_and_emit(self, buf):
-        """Pull one batch from ``buf`` and emit it. With the staging arena
-        on and a buffer that can hand out parts (the noop re-batcher),
-        the batch travels as a LIST of chunk views — the arena fills its
-        slot from the parts directly, skipping the concatenated
-        intermediate the plain ``retrieve()`` would allocate."""
+        """Pull one batch from ``buf`` and emit it. With a buffer that
+        can hand out parts (the noop re-batcher), the batch travels as a
+        LIST of chunk views — the arena fills its slot from the parts
+        directly, skipping the concatenated intermediate the plain
+        ``retrieve()`` would allocate."""
         with span('collate'):
-            if self._stager is not None and hasattr(buf, 'retrieve_parts'):
+            if hasattr(buf, 'retrieve_parts'):
                 batch = buf.retrieve_parts()
             else:
                 batch = buf.retrieve()
@@ -901,7 +897,7 @@ class JaxLoader:
     def _emit(self, host_batch):
         with span('collate'):
             if isinstance(host_batch, list):
-                # parts form (arena path only; see _retrieve_and_emit)
+                # parts form (see _retrieve_and_emit)
                 parts = [dict(p) for p in host_batch]
                 pulls = [p.pop(_PULL_FIELD, None) for p in parts]
                 if pulls[0] is None:
@@ -925,26 +921,11 @@ class JaxLoader:
                 ids, counts = np.unique(np.asarray(pull_col),
                                         return_counts=True)
                 pull_counts = dict(zip(ids.tolist(), counts.tolist()))
-            stager = self._stager
-            if stager is None:
-                # PETASTORM_TPU_STAGING=0: the pre-arena copy path (pad
-                # allocates, _to_device casts) — the reference behavior
-                # the arena's round-trip tests compare against
-                if n < self._batch_size:
-                    if self._last_batch == 'pad':
-                        host_batch = self._pad(host_batch, n)
-                    # 'short': ship as-is
-                elif self._last_batch == 'pad':
-                    host_batch[MASK_FIELD] = np.ones(n, dtype=bool)
-        if stager is None:
-            with span('h2d'):
-                device_batch = self._to_device(host_batch)
-        else:
-            # arena path: cast/pad/mask write into a preallocated slot and
-            # the transfer is dispatched async (stage_fill/h2d_dispatch/
-            # h2d_ready spans) — the consumer of batch N computes while
-            # batch N+1's transfer is in flight
-            device_batch = stager.stage(host_batch, n)
+        # cast/pad/mask write into a preallocated slot and the transfer is
+        # dispatched async (stage_fill/h2d_dispatch/h2d_ready spans) — the
+        # consumer of batch N computes while batch N+1's transfer is in
+        # flight
+        device_batch = self._stager.stage(host_batch, n)
         # provenance rides the queue as a sidecar: rows count as delivered
         # only when the consumer actually receives this item in __next__
         self._put_blocking((device_batch, pull_counts))
@@ -1024,70 +1005,28 @@ class JaxLoader:
             out[len_name] = lens[:, 0] if k == 1 else lens
         return out
 
-    def _pad(self, host_batch, n):
-        out = {}
-        for name, arr in host_batch.items():
-            arr = np.asarray(arr)
-            pad_shape = (self._batch_size - n,) + arr.shape[1:]
-            out[name] = np.concatenate([arr, np.zeros(pad_shape, arr.dtype)])
-        mask = np.zeros(self._batch_size, dtype=bool)
-        mask[:n] = True
-        out[MASK_FIELD] = mask
-        return out
-
-    def _to_device(self, host_batch):
-        """Pre-arena staging: validate + cast (allocating) + dispatch."""
-        staged = {}
-        for name, arr in host_batch.items():
-            arr = np.asarray(arr)
-            # shared classified diagnosis (ragged vs string vs null); the
-            # ragged message names pad_ragged/bucket_boundaries, and
-            # fixed-width numpy strings get the same treatment instead of
-            # jax's raw 'not a valid JAX array type'
-            staging._check_deviceable(name, arr)
-            want = self._dtypes.get(name)
-            if want is not None:
-                arr = arr.astype(want)
-            staged[name] = arr
-        return self._put_to_device(staged)
-
     def _put_to_device(self, host_batch):
-        """Dispatch one host batch to the device(s) — the transfer leg the
-        staging arena and the legacy path share (validation and dtype
-        casting already happened upstream)."""
+        """Dispatch one host batch to the device(s) — the staging
+        engine's ``put_fn`` (validation and dtype casting already
+        happened in the engine)."""
         import jax
         if self._sharding is not None:
-            return self._put_sharded(host_batch)
+            return self._dispatch_sharded(host_batch)
         # one device_put of the whole pytree: a single dispatch covering
         # every field's transfer, instead of one runtime round trip each
         return jax.device_put(host_batch)
 
-    def _put_sharded(self, host_batch):
-        """Sharded (mesh) dispatch. With the staging arena live the
-        engine already wraps this call in ``h2d_dispatch`` and counts
-        ``petastorm_tpu_h2d_bytes_total``; on the legacy path
-        (``PETASTORM_TPU_STAGING=0``) the same instrumentation lives
-        here — shard-slice bytes (what THIS host puts on the wire, not
-        the global batch), so ``h2d_overlap_share`` and the stall
-        attributor work on meshes in both modes."""
-        if self._stager is not None:
-            return self._dispatch_sharded(host_batch)
-        from petastorm_tpu.telemetry import get_registry, metrics_disabled
-        with span('h2d_dispatch'):
-            device_batch = self._dispatch_sharded(host_batch)
-        if not metrics_disabled():
-            get_registry().counter(staging.H2D_BYTES).inc(
-                sum(arr.nbytes for arr in host_batch.values()))
-        return device_batch
-
     def _dispatch_sharded(self, host_batch):
-        """One dispatch covering the whole pytree: every field's local
-        shard slices ride a single batched ``jax.device_put`` (one
-        runtime round trip) and reassemble into global ``jax.Array``s via
-        ``make_array_from_single_device_arrays`` — instead of one
-        ``make_array_from_process_local_data`` round trip per field.
-        Falls back to the per-field build when the row plan cannot be
-        proven sound for this sharding (always correct, never fast)."""
+        """Sharded (mesh) dispatch, one covering the whole pytree: every
+        field's local shard slices ride a single batched
+        ``jax.device_put`` (one runtime round trip) and reassemble into
+        global ``jax.Array``s via ``make_array_from_single_device_arrays``
+        — instead of one ``make_array_from_process_local_data`` round
+        trip per field. Falls back to the per-field build when the row
+        plan cannot be proven sound for this sharding (always correct,
+        never fast). The engine wraps this call in ``h2d_dispatch`` and
+        counts ``petastorm_tpu_h2d_bytes_total`` (shard-slice bytes: what
+        THIS host puts on the wire, not the global batch)."""
         import jax
         if not host_batch:
             return {}
@@ -1218,25 +1157,16 @@ class JaxLoader:
             'pulls_in_flight': len(self._pull_info),
             # staging arena (docs/telemetry.md "Host→device staging"):
             # slot slabs only grow at startup / on a new bucket shape —
-            # steady growth here means the arena is not being reused.
-            # Between passes the stager of the FINISHED pass is not the
-            # truth about the knob, so report the knob itself then.
-            'staging_enabled': (stager is not None
-                                if self._stage_thread is not None
-                                and self._stage_thread.is_alive()
-                                else staging.staging_enabled()),
-            'staging_slots_allocated': (stager.slabs_allocated
-                                        if stager is not None else 0),
+            # steady growth here means the arena is not being reused
+            'staging_slots_allocated': stager.slabs_allocated,
             # fused decode (docs/troubleshoot.md "decode is batched but
             # not fused"): where decode ran for this pass's batches
             'fused_decode_mode': self._fused_decode_mode(),
-            'fused_decode_rows': (stager.fused_rows
-                                  if stager is not None else 0),
+            'fused_decode_rows': stager.fused_rows,
             # staging autotuner (jax/autotune.py): live depth settings +
             # how many adjustments this loader has made
             'staging_prefetch': self._prefetch,
-            'staging_slot_depth': (stager.num_slots
-                                   if stager is not None else 0),
+            'staging_slot_depth': stager.num_slots,
             'staging_autotune': self._autotuner is not None,
             'staging_autotune_decisions': (self._autotuner.decisions
                                            if self._autotuner is not None
@@ -1253,9 +1183,8 @@ class JaxLoader:
         decode-to-destination pass), ``'batched'`` (classic worker-side
         or loader-materialized batch decode), or ``'pending'`` before
         the first delivery says which."""
-        stager = self._stager
-        if stager is not None and stager.fused_rows:
-            return stager.fused_mode
+        if self._stager.fused_rows:
+            return self._stager.fused_mode
         if self._fused_fallback is not None or self._batches_delivered:
             return 'batched'
         return 'pending'
@@ -1270,9 +1199,10 @@ class JaxLoader:
 
     def dump_trace(self, path):
         """Export the per-item trace (ventilate → worker stages →
-        queue_wait → collate/h2d, across every pool flavor) as Chrome
-        trace-event JSON; needs ``PETASTORM_TPU_TRACE=1`` during the run
-        (docs/telemetry.md). Returns the number of events written."""
+        queue_wait → collate/h2d_dispatch, across every pool flavor) as
+        Chrome trace-event JSON; needs ``PETASTORM_TPU_TRACE=1`` during
+        the run (docs/telemetry.md). Returns the number of events
+        written."""
         from petastorm_tpu.telemetry import dump_trace
         return dump_trace(path)
 
@@ -1453,14 +1383,12 @@ class JaxLoader:
             'prefetch': self._prefetch,
             'consumer_wait_s': round(self._consumer_wait_s, 3),
             'stage_backpressure_s': round(self._stage_blocked_s, 3),
-            'staging_enabled': self._stager is not None,
             'fused_decode_mode': self._fused_decode_mode(),
             # per-host staging view (the "One host starves the mesh"
             # runbook reads these across every host's /health endpoint)
             'h2d_overlap_share': self._h2d_overlap_share(),
             'staging_prefetch': self._prefetch,
-            'staging_slot_depth': (self._stager.num_slots
-                                   if self._stager is not None else 0),
+            'staging_slot_depth': self._stager.num_slots,
             'staging_autotune_decisions': (self._autotuner.decisions
                                            if self._autotuner is not None
                                            else 0),

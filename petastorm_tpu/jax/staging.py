@@ -1,13 +1,10 @@
 """Zero-copy staging arena + double-buffered async host→device transfer.
 
-The final hop of the JAX path used to be copy-heavy and single-buffered:
-``_pad``/``_to_device`` allocated fresh host arrays per batch
-(``np.asarray``/``astype``/``np.concatenate`` all copy — up to twice per
-batch when a spanning batch also casts) and the staging thread had no
-dispatch/recycle discipline, so the host buffers feeding a transfer could
-not be prepared ahead. This module replaces that path with a
-:class:`StagingEngine` that picks the cheapest correct strategy per batch
-and per backend:
+The final hop of the JAX path: every batch the loader emits goes through a
+:class:`StagingEngine`, which collates, pads and casts it straight into
+the buffers the transfer reads (never an ``astype``/``np.concatenate``
+intermediate) and dispatches the transfer without waiting for it. The
+engine picks the cheapest correct strategy per batch and per backend:
 
 * **Direct dispatch** — a batch that needs NO assembly (one chunk view, no
   dtype cast, no tail pad) skips every copy: the source views go straight
@@ -36,7 +33,7 @@ and per backend:
   aligned array aliases; a fresh large numpy allocation is page-aligned),
   so a reused slot would either alias (corrupting a held batch on
   recycle) or force a second real copy. Instead each batch assembles into
-  fresh buffers — the ONE copy legacy paid — and the dispatch aliases
+  fresh buffers — one copy — and the dispatch aliases
   them for free; the buffers are never touched again, making aliasing
   harmless by construction.
 
@@ -45,11 +42,10 @@ as the first dispatch reveals a host-backed (``'cpu'`` platform) target;
 unknown array types conservatively count as host-backed (fresh assembly is
 the always-correct strategy).
 
-Knobs (docs/env_knobs.md): ``PETASTORM_TPU_STAGING=0`` disables the engine
-(the loader falls back to the pre-arena copy path — also the reference
-behavior for exact-value comparison tests); ``PETASTORM_TPU_STAGING_SLOTS``
-sizes the ring. Both are read once and cached; :func:`refresh_staging`
-re-reads (the established knob discipline).
+Knob (docs/env_knobs.md): ``PETASTORM_TPU_STAGING_SLOTS`` sizes the ring.
+It is read once and cached; :func:`refresh_staging` re-reads it (the
+established knob discipline). The tests hold the engine's values to a
+plain numpy reference (``petastorm_tpu/test_util/reference.py``).
 
 Telemetry (docs/telemetry.md): per-batch ``stage_fill`` /
 ``h2d_dispatch`` / ``h2d_ready`` spans (which double as trace events when
@@ -89,17 +85,8 @@ MASK_FIELD = 'valid_mask'
 
 _MIN_SLOTS = 2
 
-# knob caches (refresh_staging() re-reads); None = not yet resolved
-_enabled = None
+# knob cache (refresh_staging() re-reads); None = not yet resolved
 _slots = None
-
-
-def staging_enabled():
-    """True unless ``PETASTORM_TPU_STAGING`` disables the arena."""
-    global _enabled
-    if _enabled is None:
-        _enabled = not knobs.is_disabled('PETASTORM_TPU_STAGING')
-    return _enabled
 
 
 def staging_slots():
@@ -113,12 +100,11 @@ def staging_slots():
 
 
 def refresh_staging():
-    """Re-read both staging knobs (tests, long-lived processes flipping
-    the env); the next :func:`make_stager` call sees the new values.
+    """Re-read the staging knob (tests, long-lived processes flipping
+    the env); the next :func:`make_stager` call sees the new value.
     Also runs as part of ``petastorm_tpu.telemetry.refresh()``, the
     process's one re-read-every-knob entry point."""
-    global _enabled, _slots
-    _enabled = None
+    global _slots
     _slots = None
 
 
@@ -126,11 +112,7 @@ register_refresh(refresh_staging)
 
 
 def make_stager(batch_size, dtypes, last_batch, put_fn):
-    """A :class:`StagingEngine` for one staging pass, or None when
-    ``PETASTORM_TPU_STAGING=0`` tells the loader to use its pre-arena
-    copy path."""
-    if not staging_enabled():
-        return None
+    """A :class:`StagingEngine` for one staging pass."""
     return StagingEngine(batch_size, dtypes, last_batch, put_fn,
                          num_slots=staging_slots())
 
@@ -243,8 +225,8 @@ class StagingEngine:
 
     def _resolve_dtypes(self, parts):
         """Per-field assembly dtype: the ``dtypes=`` policy wins;
-        otherwise mixed-dtype parts PROMOTE exactly like the
-        ``np.concatenate`` the pre-arena path performed (an int32 chunk
+        otherwise mixed-dtype parts PROMOTE exactly like
+        ``np.concatenate`` would (an int32 chunk
         followed by an int64 one must yield int64, never a wrapping
         downcast into the first chunk's dtype)."""
         resolved = {}
@@ -375,8 +357,8 @@ class StagingEngine:
         return device_batch
 
     def _stage_fresh(self, parts, dtype_map, n, with_mask):
-        """Host-backed backends: assemble into FRESH buffers (the one
-        copy the legacy path also paid) and let the runtime zero-copy
+        """Host-backed backends: assemble into FRESH buffers (one copy)
+        and let the runtime zero-copy
         them into the device handle — never reused, so aliasing is
         harmless by construction."""
         with span('stage_fill'):
@@ -466,7 +448,7 @@ class StagingEngine:
                 if column.shape[1:] != dst.shape[1:]:
                     # explicit, BEFORE the copy: np.copyto would happily
                     # BROADCAST a narrower chunk into the slot — silent
-                    # corruption where the legacy np.concatenate raised
+                    # corruption where np.concatenate would raise
                     raise ValueError(
                         'staging: field %r chunk of shape %s does not '
                         'fit the batch slot of shape %s; variable-shape '

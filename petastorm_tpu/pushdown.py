@@ -22,9 +22,8 @@ exactly here):
 * everything uncertain is **kept**: a failed footer read, a column
   without statistics, an incomparable type, an arbitrary predicate — a
   wrong prune would silently lose rows, so the planner only ever prunes
-  what the statistics *prove* empty. `PETASTORM_TPU_PUSHDOWN=0` turns
-  the whole planner off (the comparison oracle the exact-parity tests
-  read against).
+  what the statistics *prove* empty. The exact-parity tests compare
+  against a plain pyarrow read (``petastorm_tpu/test_util/reference.py``).
 
 What the prover understands (everything else is `arbitrary-predicate`):
 
@@ -100,27 +99,6 @@ def _fresh_summary():
 
 
 _summary = _fresh_summary()
-
-
-def pushdown_enabled():
-    """Plan-time pruning gate. ``PETASTORM_TPU_PUSHDOWN=0`` turns the
-    WHOLE selective-read fast path off (this planner and the worker's
-    late-materialization shape — the decode-everything-then-filter
-    exact-parity oracle); ``PETASTORM_TPU_PUSHDOWN_PRUNE=0`` turns off
-    only this planner, keeping late materialization — the attribution
-    rung the bench's ``selective_read`` section measures. Read at Reader
-    construction, never on the hot path, so deliberately cache-free."""
-    return (not knobs.is_disabled('PETASTORM_TPU_PUSHDOWN')
-            and not knobs.is_disabled('PETASTORM_TPU_PUSHDOWN_PRUNE'))
-
-
-def fullscan_oracle():
-    """True when ``PETASTORM_TPU_PUSHDOWN=0`` demands the worker's
-    decode-everything-then-filter oracle shape (read every column, decode
-    every row, filter the decoded arrays after the fact) — the
-    comparison baseline for exact-parity tests and the bench's
-    full-scan-priced rung. Never the production path."""
-    return knobs.is_disabled('PETASTORM_TPU_PUSHDOWN')
 
 
 def planner_summary():
@@ -543,4 +521,4 @@ def plan_rowgroup_pruning(dataset_info, pieces, piece_indices,
 
 
 __all__ = ['PushdownPlan', 'StatsIndex', 'plan_rowgroup_pruning',
-           'planner_summary', 'pushdown_enabled', 'reset_for_tests']
+           'planner_summary', 'reset_for_tests']

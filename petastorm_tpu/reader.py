@@ -456,25 +456,23 @@ class Reader:
         # empty against the predicate never reach the pool. Pruning runs
         # AFTER sharding and keeps every item in the list, so shard
         # assignment, item indices and checkpoint identities are
-        # bit-identical to an unpruned (PETASTORM_TPU_PUSHDOWN=0) reader;
-        # the pruned items are simply never ventilated and the epoch
+        # bit-identical to a reader without the predicate; the pruned items are simply never ventilated and the epoch
         # accounting below treats them as completed-with-zero-rows.
         self._pruned_items = frozenset()
         self._pushdown_plan = None
         if worker_predicate is not None \
                 and worker_predicate is not self._filters_born:
             from petastorm_tpu import pushdown
-            if pushdown.pushdown_enabled():
-                with span('rowgroup_prune'):
-                    self._pushdown_plan = pushdown.plan_rowgroup_pruning(
-                        dataset_info, all_pieces, piece_indices,
-                        predicate=worker_predicate,
-                        stored_schema=self.stored_schema)
-                if self._pushdown_plan.pruned:
-                    pruned_pieces = set(self._pushdown_plan.pruned)
-                    self._pruned_items = frozenset(
-                        it['item_index'] for it in items
-                        if it['piece_index'] in pruned_pieces)
+            with span('rowgroup_prune'):
+                self._pushdown_plan = pushdown.plan_rowgroup_pruning(
+                    dataset_info, all_pieces, piece_indices,
+                    predicate=worker_predicate,
+                    stored_schema=self.stored_schema)
+            if self._pushdown_plan.pruned:
+                pruned_pieces = set(self._pushdown_plan.pruned)
+                self._pruned_items = frozenset(
+                    it['item_index'] for it in items
+                    if it['piece_index'] in pruned_pieces)
 
         self._pool = _make_pool(reader_pool_type, workers_count,
                                 results_queue_size,
@@ -962,9 +960,8 @@ class Reader:
             saved = [tuple(ident) for ident in saved]
             if saved != self._items_identity:
                 # Index-space drift: the SAVING reader's item list differs
-                # from ours — a PETASTORM_TPU_PUSHDOWN flip across a
-                # resume changes the filters= pre-shard prune, rewritten
-                # files change the statistics. Local indices would then
+                # from ours — rewritten files change the statistics the
+                # filters= pre-shard prune reads. Local indices would then
                 # silently name DIFFERENT row-groups (row loss), so
                 # translate through the saver's per-index identities:
                 # identities absent from our list drop (their row-groups

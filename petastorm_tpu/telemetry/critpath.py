@@ -61,7 +61,7 @@ _PRIORITY = (
     'decode_fused', 'decode', 'late_materialize', 'transform', 'filter',
     'collate', 'pack', 'encode', 'write_flush', 'compact', 'cache_fill',
     'cache_hit_read', 'serialize', 'deserialize', 'io', 'readahead_fetch',
-    'rowgroup_prune', 'stage_fill', 'h2d_dispatch', 'h2d', 'h2d_ready',
+    'rowgroup_prune', 'stage_fill', 'h2d_dispatch', 'h2d_ready',
     'autotune', 'ventilate', 'queue_wait', 'stage_backpressure',
     'loader_next',
 )
@@ -246,7 +246,7 @@ def predict_speedup(stage, factor, events=None, report=None):
 #: which stage territory each autotuner action treats as the bottleneck
 #: (deepen/raise = the tuner believes that side is the wall) or as slack
 #: (shed/lower/restore = the tuner believes that side has headroom)
-_H2D_SIDE = frozenset(('h2d', 'h2d_ready', 'h2d_dispatch', 'stage_fill'))
+_H2D_SIDE = frozenset(('h2d_ready', 'h2d_dispatch', 'stage_fill'))
 _IO_SIDE = frozenset(('io', 'readahead_fetch'))
 _ACTION_TERRITORY = {
     'deepen_slots': ('bottleneck', _H2D_SIDE),

@@ -11,7 +11,7 @@ queues and the service protocol's WORK frames, as a reserved
 ``worker.process`` — and worker-side events travel back piggybacked on
 the same metric-delta frames the pools already ship (process-pool
 markers, service DONE messages). Consumer-side events (``queue_wait``,
-``collate``, ``h2d``, the dispatcher's dispatch/re-ventilation/dedup
+``collate``, ``stage_fill``, ``h2d_dispatch``, the dispatcher's dispatch/re-ventilation/dedup
 instants) land in the same per-process flight recorder
 (:mod:`~petastorm_tpu.telemetry.recorder`), so one export shows the whole
 distributed life of an item — including both attempts of a re-ventilated

@@ -38,6 +38,8 @@ _FAST_PROCESS_KEEP = frozenset([
     'tests/test_end_to_end.py::test_simple_read_all_fields[process]',
     'tests/test_workers_pool.py::test_identity_roundtrip[process-2]',
     'tests/test_ngram.py::TestNGramEndToEnd::test_basic[process]',
+    'tests/test_pushdown.py::TestExactParity::'
+    'test_row_drop_partition_parity[process]',
 ])
 
 # pool param id component, wherever it lands in a (possibly stacked)

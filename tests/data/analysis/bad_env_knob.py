@@ -5,7 +5,7 @@ import os
 from petastorm_tpu.telemetry import knobs
 
 # finding: raw os.environ.get outside telemetry/knobs.py
-_RAW_GET = os.environ.get('PETASTORM_TPU_STAGING', '')
+_RAW_GET = os.environ.get('PETASTORM_TPU_STAGING_SLOTS', '')
 
 # finding: raw subscript read
 _RAW_SUB = os.environ['PETASTORM_TPU_METRICS']

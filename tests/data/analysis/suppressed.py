@@ -16,4 +16,4 @@ class SuppressedPool:
 
 
 # suppressed via `all`
-_RAW = os.environ.get('PETASTORM_TPU_STAGING')  # pipecheck: disable=all
+_RAW = os.environ.get('PETASTORM_TPU_STAGING_SLOTS')  # pipecheck: disable=all

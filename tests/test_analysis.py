@@ -283,7 +283,7 @@ def test_analyze_source_on_clean_snippet():
 
 def test_select_narrows_rules():
     source = ("import os\nimport threading\n"
-              "_RAW = os.environ.get('PETASTORM_TPU_STAGING')\n"
+              "_RAW = os.environ.get('PETASTORM_TPU_STAGING_SLOTS')\n"
               "t = threading.Thread(target=print)\nt.start()\n")
     only_env = analyze_source(source, select=['env-knob'])
     assert [f.rule for f in only_env] == ['env-knob']

@@ -4,7 +4,9 @@ Covers the whole chain: the codecs' ``decode_batch(..., out=)``
 destination API (incl. the nulls path's zero-fill + red-zone
 no-overrun fixture), the worker's deferral gates, the
 ``EncodedImageColumn`` carrier, the staging arena's fused fill
-(exact-value parity against the pure-Python decode oracle), every
+(exact-value parity against the plain reference,
+``petastorm_tpu/test_util/reference.py``, and through the cv2 fallback
+decoder), every
 fallback mode the troubleshoot runbook names, the sanitizer interplay
 (canaries intact across fused refills), and the ``perf``-marked
 zero-per-image-intermediates tracemalloc guard."""
@@ -30,6 +32,7 @@ from petastorm_tpu.jax import make_jax_loader
 from petastorm_tpu.jax import staging
 from petastorm_tpu.reader import make_batch_reader
 from petastorm_tpu.unischema import Unischema, UnischemaField
+from petastorm_tpu.test_util import reference
 from petastorm_tpu.test_util.parity import assert_same_rows_by_id
 
 
@@ -321,7 +324,7 @@ def test_transform_spec_declines_deferral(image_dataset):
     assert isinstance(batch.image, np.ndarray)
 
 
-# -- loader end-to-end: fused vs the pure-Python oracle -----------------------
+# -- loader end-to-end: fused vs the plain reference --------------------------
 
 
 def _collect(url, **kw):
@@ -339,17 +342,21 @@ def _assert_same(batches_a, batches_b):
 
 def test_fused_loader_matches_pure_python_oracle(image_dataset):
     """The acceptance gate: decode fused into staging buffers must be
-    value-identical to the legacy path with the native layer OFF — the
-    pure-Python cv2 decode oracle (png: lossless + path-independent)."""
+    value-identical to the rows written (png: lossless +
+    path-independent), with the native decoders and with the native
+    layer OFF — the cv2 fallback, the only decoder where the native
+    build fails."""
     url, rows = image_dataset
     fused_batches, diag = _collect(url, batch_size=24)
     assert diag['fused_decode_mode'] in ('fused-into-slot',
                                          'fused-into-slab')
     assert diag['fused_decode_rows'] == 96
-    with _env(PETASTORM_TPU_STAGING='0', PETASTORM_TPU_NATIVE='0'):
-        oracle_batches, oracle_diag = _collect(url, batch_size=24)
-    assert oracle_diag['fused_decode_mode'] == 'batched'
-    _assert_same(fused_batches, oracle_batches)
+    with _env(PETASTORM_TPU_NATIVE='0'):
+        cv2_batches, cv2_diag = _collect(url, batch_size=24)
+    assert cv2_diag['fused_decode_rows'] == 96
+    expected = reference.loader_batches(rows, 24)
+    _assert_same(fused_batches, expected)
+    _assert_same(cv2_batches, expected)
     # and against the source pixels themselves
     by_id = {}
     for b in fused_batches:
@@ -360,7 +367,7 @@ def test_fused_loader_matches_pure_python_oracle(image_dataset):
 
 
 def test_fused_pad_tail_zero_fills_and_masks(image_dataset):
-    url, _ = image_dataset
+    url, rows = image_dataset
     batches, diag = _collect(url, batch_size=36, last_batch='pad')
     assert diag['fused_decode_rows'] == 96
     tail = batches[-1]
@@ -368,9 +375,8 @@ def test_fused_pad_tail_zero_fills_and_masks(image_dataset):
     assert mask[:24].all() and not mask[24:].any()
     assert not tail['image'][24:].any()  # padded rows are zero, not stale
     assert all(b['valid_mask'].all() for b in batches[:-1])
-    with _env(PETASTORM_TPU_STAGING='0', PETASTORM_TPU_NATIVE='0'):
-        oracle, _ = _collect(url, batch_size=36, last_batch='pad')
-    _assert_same(batches, oracle)
+    _assert_same(batches, reference.loader_batches(rows, 36,
+                                                   last_batch='pad'))
 
 
 def _assert_same_batches(batches_a, batches_b):
@@ -381,11 +387,13 @@ def _assert_same_batches(batches_a, batches_b):
             np.testing.assert_array_equal(a[name], b[name])
 
 
-def _assert_shuffled_rows_fuse(url, pool):
+def _assert_shuffled_rows_fuse(url, rows, pool):
     """Shuffled rows, fused: the random buffer gathers encoded cells and
     the arena decodes the drawn rows into its buffers. One worker fixes
-    the row-group order, so one seed must give the oracle's batches
-    exactly — the same rows, in the same batches, in the same order."""
+    the row-group order, so one seed must give the batches of the
+    worker-decoded read (cv2 fallback decoder) exactly — the same rows,
+    in the same batches, in the same order — and the rows are the rows
+    written."""
     kw = dict(batch_size=24, shuffle_rows=True, seed=3, workers_count=1,
               reader_pool_type=pool)
     batches, diag = _collect(url, **kw)
@@ -393,20 +401,20 @@ def _assert_shuffled_rows_fuse(url, pool):
                                          'fused-into-slab')
     assert diag['fused_decode_rows'] == 96
     assert 'fused_decode_fallback' not in diag
-    with _env(PETASTORM_TPU_STAGING='0', PETASTORM_TPU_NATIVE='0'):
-        oracle, oracle_diag = _collect(url, **kw)
-    assert oracle_diag['fused_decode_mode'] == 'batched'
-    _assert_same_batches(batches, oracle)
+    with _env(PETASTORM_TPU_NATIVE='0'):
+        decoded, decoded_diag = _collect(url, defer_image_decode=False,
+                                         **kw)
+    assert decoded_diag['fused_decode_mode'] == 'batched'
+    _assert_same_batches(batches, decoded)
+    _assert_same(batches, reference.loader_batches(rows, 24))
 
 
 def test_shuffled_rows_fall_back_and_match_decoded_path(image_dataset):
-    url, _ = image_dataset
-    _assert_shuffled_rows_fuse(url, 'thread')
+    _assert_shuffled_rows_fuse(*image_dataset, 'thread')
 
 
 def test_shuffled_rows_fuse_across_the_process_pool(image_dataset):
-    url, _ = image_dataset
-    _assert_shuffled_rows_fuse(url, 'process')
+    _assert_shuffled_rows_fuse(*image_dataset, 'process')
 
 
 def test_dtype_cast_materializes_and_matches(image_dataset):
@@ -416,9 +424,8 @@ def test_dtype_cast_materializes_and_matches(image_dataset):
     assert batches[0]['image'].dtype == np.float32
     assert diag['fused_decode_mode'] == 'batched'
     assert diag.get('fused_decode_fallback') == 'dtype-cast'
-    with _env(PETASTORM_TPU_STAGING='0', PETASTORM_TPU_NATIVE='0'):
-        oracle, _ = _collect(url, **kw)
-    _assert_same(batches, oracle)
+    _, rows = image_dataset
+    _assert_same(batches, reference.loader_batches(rows, **kw))
 
 
 def test_fused_records_decode_fused_stage(image_dataset):

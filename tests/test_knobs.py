@@ -16,12 +16,12 @@ def test_unregistered_knob_raises():
 
 
 def test_raw_and_get_str(monkeypatch):
-    monkeypatch.delenv('PETASTORM_TPU_STAGING', raising=False)
-    assert knobs.raw('PETASTORM_TPU_STAGING') is None
-    assert knobs.get_str('PETASTORM_TPU_STAGING') == ''
-    monkeypatch.setenv('PETASTORM_TPU_STAGING', '  0  ')
-    assert knobs.raw('PETASTORM_TPU_STAGING') == '  0  '
-    assert knobs.get_str('PETASTORM_TPU_STAGING') == '0'
+    monkeypatch.delenv('PETASTORM_TPU_STAGING_SLOTS', raising=False)
+    assert knobs.raw('PETASTORM_TPU_STAGING_SLOTS') is None
+    assert knobs.get_str('PETASTORM_TPU_STAGING_SLOTS') == ''
+    monkeypatch.setenv('PETASTORM_TPU_STAGING_SLOTS', '  0  ')
+    assert knobs.raw('PETASTORM_TPU_STAGING_SLOTS') == '  0  '
+    assert knobs.get_str('PETASTORM_TPU_STAGING_SLOTS') == '0'
 
 
 @pytest.mark.parametrize('value,disabled', [
@@ -93,19 +93,17 @@ def test_native_disabled_semantics(monkeypatch):
 
 def test_staging_knobs_via_refresh(monkeypatch):
     from petastorm_tpu.jax import staging
-    monkeypatch.setenv('PETASTORM_TPU_STAGING', '0')
     monkeypatch.setenv('PETASTORM_TPU_STAGING_SLOTS', '5')
     staging.refresh_staging()
     try:
-        assert staging.staging_enabled() is False
         assert staging.staging_slots() == 5
-        monkeypatch.setenv('PETASTORM_TPU_STAGING', '')
         monkeypatch.setenv('PETASTORM_TPU_STAGING_SLOTS', '1')  # under floor
         staging.refresh_staging()
-        assert staging.staging_enabled() is True
         assert staging.staging_slots() == 2
+        monkeypatch.setenv('PETASTORM_TPU_STAGING_SLOTS', '')
+        staging.refresh_staging()
+        assert staging.staging_slots() == 2    # unset: the default
     finally:
-        monkeypatch.delenv('PETASTORM_TPU_STAGING', raising=False)
         monkeypatch.delenv('PETASTORM_TPU_STAGING_SLOTS', raising=False)
         staging.refresh_staging()
 

@@ -2,11 +2,12 @@
 projection pushdown + late materialization, and predicate cacheability.
 
 The load-bearing contract is EXACT PARITY: a pruned + late-materialized
-epoch must deliver the identical row multiset as the
-decode-everything-then-filter oracle (``PETASTORM_TPU_PUSHDOWN=0``),
-across pool types and under sharding — and pruning must be conservative
-everywhere (null-bearing columns, missing statistics, faulted footer
-reads degrade to unpruned reads, never to a wrong answer).
+epoch must deliver the identical row multiset as a plain pyarrow read
+(``petastorm_tpu/test_util/reference.py``: pyarrow's own ``filters=``,
+or the predicate's ``do_include`` over the rows it read), across pool
+types and under sharding — and pruning must be conservative everywhere
+(null-bearing columns, missing statistics, faulted footer reads degrade
+to unpruned reads, never to a wrong answer).
 """
 
 import os
@@ -20,6 +21,8 @@ from petastorm_tpu import pushdown
 from petastorm_tpu import telemetry as T
 from petastorm_tpu.filters import FiltersPredicate
 from petastorm_tpu.predicates import in_lambda, in_negate, in_reduce, in_set
+from petastorm_tpu.test_util import reference
+from tests.test_common import TestSchema
 
 
 @pytest.fixture(autouse=True)
@@ -29,26 +32,38 @@ def _fresh_telemetry():
     T.reset_for_tests()
 
 
-@pytest.fixture()
-def oracle_env(monkeypatch):
-    """Flip the whole selective-read fast path off (the comparison
-    oracle) for the duration of a ``with``-less block via a callable."""
-    def arm(value='0', knob='PETASTORM_TPU_PUSHDOWN'):
-        monkeypatch.setenv(knob, value)
-    return arm
+def _read_ids(url, plain=False, pool='thread', predicate=None, **kwargs):
+    """Sorted delivered ids; ``plain=True`` gives the plain read's
+    instead (the reader's other options do not change which rows
+    match, so the plain read ignores them)."""
+    if plain:
+        return sorted(int(row['id']) for row in _plain_rows(url, predicate))
+    with make_batch_reader(url, reader_pool_type=pool, predicate=predicate,
+                           shuffle_row_groups=False, **kwargs) as reader:
+        return sorted(int(i) for batch in reader for i in batch.id)
 
 
-def _read_ids(url, oracle=False, pool='thread', **kwargs):
-    env = dict(os.environ)
-    if oracle:
-        os.environ['PETASTORM_TPU_PUSHDOWN'] = '0'
-    try:
-        with make_batch_reader(url, reader_pool_type=pool,
-                               shuffle_row_groups=False, **kwargs) as reader:
-            return sorted(int(i) for batch in reader for i in batch.id)
-    finally:
-        os.environ.clear()
-        os.environ.update(env)
+def _plain_rows(url, predicate, schema=None, columns=None):
+    """The plain read: pyarrow's own ``filters=`` for a
+    FiltersPredicate, else the predicate's ``do_include`` over every
+    row pyarrow read."""
+    if isinstance(predicate, FiltersPredicate):
+        return reference.read_rows(url, columns=columns, schema=schema,
+                                   filters=predicate.clauses)
+    rows = reference.read_rows(url, columns=columns, schema=schema)
+    return rows if predicate is None else reference.matching_rows(
+        rows, predicate)
+
+
+def _do_include_ids(url, predicate, null=None):
+    """Sorted ids of the rows pyarrow read that ``predicate.do_include``
+    accepts, with null cells of ``x`` read as ``null``."""
+    rows = reference.read_rows(url)
+    for row in rows:
+        if row['x'] is None:
+            row['x'] = null
+    return sorted(row['id'] for row in reference.matching_rows(rows,
+                                                               predicate))
 
 
 # ---------------------------------------------------------------------------
@@ -96,7 +111,7 @@ class TestProver:
                                    expected_pruned):
         pred = FiltersPredicate(filters)
         got = _read_ids(two_rowgroup_url, predicate=pred)
-        assert got == _read_ids(two_rowgroup_url, oracle=True,
+        assert got == _read_ids(two_rowgroup_url, plain=True,
                                 predicate=pred)
         assert pushdown.planner_summary()['rowgroups_pruned'] == \
             expected_pruned
@@ -124,7 +139,7 @@ class TestProver:
                      in_negate(FiltersPredicate([('x', '<', 15)]))):
             T.reset_for_tests()
             got = _read_ids(two_rowgroup_url, predicate=pred)
-            assert got == _read_ids(two_rowgroup_url, oracle=True,
+            assert got == _read_ids(two_rowgroup_url, plain=True,
                                     predicate=pred)
             summary = pushdown.planner_summary()
             assert summary['rowgroups_pruned'] == 0
@@ -206,7 +221,7 @@ class TestNullSafety:
         pred = in_set([None, 'zz'], 'x')
         got = _read_ids(null_bearing_url, predicate=pred)
         assert got == [1]
-        assert got == _read_ids(null_bearing_url, oracle=True,
+        assert got == _read_ids(null_bearing_url, plain=True,
                                 predicate=pred)
         assert pushdown.planner_summary()['rowgroups_pruned'] == 1
 
@@ -216,7 +231,7 @@ class TestNullSafety:
         # NaN DOES match '!='/'not in' at worker evaluation — so a
         # [5, null, 5] row-group must NOT be pruned against '!= 5' even
         # though its non-null min==max==5 (pre-fix, the pruned read lost
-        # the NaN row the oracle delivers).
+        # the NaN row the plain read delivers).
         import pyarrow as pa
         import pyarrow.parquet as pq
         path = str(tmp_path / 'numnulls')
@@ -235,7 +250,10 @@ class TestNullSafety:
             T.reset_for_tests()
             pred = FiltersPredicate(filters)
             got = _read_ids(url, predicate=pred)
-            assert got == _read_ids(url, oracle=True, predicate=pred), \
+            # pyarrow's filters= drops a null under '!=', where the
+            # reader's decoded NaN matches: the reference is the
+            # predicate's own do_include over the rows read
+            assert got == _do_include_ids(url, pred, null=float('nan')), \
                 filters
             assert got == [1, 3, 4, 5], (filters, got)
             # the null-bearing group was kept; the null-free one with
@@ -276,8 +294,9 @@ class TestNullSafety:
             T.reset_for_tests()
             pred = FiltersPredicate(filters)
             got = _read_ids(url, predicate=pred)
-            assert got == _read_ids(url, oracle=True, predicate=pred), \
-                filters
+            # pyarrow's filters= prunes this row-group by its own NaN-blind
+            # statistics: the reference is the predicate's do_include
+            assert got == _do_include_ids(url, pred), filters
             assert got == [1], (filters, got)
             assert pushdown.planner_summary()['rowgroups_pruned'] == 0
 
@@ -288,28 +307,29 @@ class TestNullSafety:
         pred = FiltersPredicate([('x', '>', 'f')])
         got = _read_ids(null_bearing_url, predicate=pred)
         assert got == [3, 4, 5]
-        assert got == _read_ids(null_bearing_url, oracle=True,
+        assert got == _read_ids(null_bearing_url, plain=True,
                                 predicate=pred)
         assert pushdown.planner_summary()['rowgroups_pruned'] == 1
 
 
 # ---------------------------------------------------------------------------
-# Exact parity: pruned + late-materialized vs the full-scan oracle
+# Exact parity: pruned + late-materialized vs the plain read
 # ---------------------------------------------------------------------------
 
 
-def _read_rows(url, oracle=False, **kwargs):
-    env = dict(os.environ)
-    if oracle:
-        os.environ['PETASTORM_TPU_PUSHDOWN'] = '0'
-    try:
-        with make_reader(url, shuffle_row_groups=False, **kwargs) as reader:
-            return sorted(
-                (row.id, row.image_png.tobytes(), row.matrix.tobytes())
-                for row in reader)
-    finally:
-        os.environ.clear()
-        os.environ.update(env)
+def _read_rows(url, plain=False, predicate=None, **kwargs):
+    """Sorted ``(id, image_png bytes, matrix bytes)``; ``plain=True``
+    gives the plain read's, decoded by the codecs' own ``decode``."""
+    if plain:
+        return sorted(
+            (row['id'], row['image_png'].tobytes(), row['matrix'].tobytes())
+            for row in _plain_rows(url, predicate, schema=TestSchema,
+                                   columns=['id', 'image_png', 'matrix']))
+    with make_reader(url, shuffle_row_groups=False, predicate=predicate,
+                     **kwargs) as reader:
+        return sorted(
+            (row.id, row.image_png.tobytes(), row.matrix.tobytes())
+            for row in reader)
 
 
 class TestExactParity:
@@ -320,20 +340,19 @@ class TestExactParity:
         pred = FiltersPredicate([[('id', '<', 12)], [('id', '>=', 95)]])
         got = _read_ids(synthetic_dataset.url, pool=pool, predicate=pred,
                         workers_count=2)
-        oracle = _read_ids(synthetic_dataset.url, oracle=True, pool=pool,
-                           predicate=pred, workers_count=2)
-        assert got == oracle == list(range(12)) + list(range(95, 100))
+        plain = _read_ids(synthetic_dataset.url, plain=True, predicate=pred)
+        assert got == plain == list(range(12)) + list(range(95, 100))
         assert T.get_registry().counter_value(pushdown.ROWGROUPS_PRUNED) > 0
 
     def test_heavy_column_value_parity(self, synthetic_dataset):
         # pixels and ndarrays decoded late must be byte-identical to the
-        # oracle's decode-everything output
+        # plain read's, decoded by the codecs
         pred = FiltersPredicate([('id', 'in', (3, 31, 47, 99))])
         got = _read_rows(synthetic_dataset.url, predicate=pred)
-        oracle = _read_rows(synthetic_dataset.url, oracle=True,
-                            predicate=pred)
+        plain = _read_rows(synthetic_dataset.url, plain=True,
+                           predicate=pred)
         assert [g[0] for g in got] == [3, 31, 47, 99]
-        assert got == oracle
+        assert got == plain
         registry = T.get_registry()
         assert registry.counter_value(
             'petastorm_tpu_late_materialized_rows_total') == 4
@@ -346,18 +365,20 @@ class TestExactParity:
         for cur in (0, 1):
             got = _read_ids(synthetic_dataset.url, predicate=pred,
                             cur_shard=cur, shard_count=2)
-            oracle = _read_ids(synthetic_dataset.url, oracle=True,
-                               predicate=pred, cur_shard=cur, shard_count=2)
             # pruning runs AFTER sharding, so each shard's row set is
-            # bit-identical to its unpruned self — not just the union
-            assert got == oracle
+            # its predicate-free self filtered — not just the union
+            shard = _read_ids(synthetic_dataset.url, cur_shard=cur,
+                              shard_count=2)
+            assert got == [i for i in shard if i < 30]
             per_shard.append(got)
         assert sorted(per_shard[0] + per_shard[1]) == list(range(30))
 
     def test_prune_only_knob_keeps_late_materialization(
-            self, synthetic_dataset, monkeypatch):
-        monkeypatch.setenv('PETASTORM_TPU_PUSHDOWN_PRUNE', '0')
-        pred = FiltersPredicate([('id', 'in', (3, 47))])
+            self, synthetic_dataset):
+        # a predicate the statistics cannot prune (arbitrary code): every
+        # row-group reaches the worker, and only the survivors' heavy
+        # columns materialize
+        pred = in_lambda(['id'], lambda v: v['id'] in (3, 47))
         with make_batch_reader(synthetic_dataset.url, shuffle_row_groups=False,
                                predicate=pred) as reader:
             got = sorted(int(i) for b in reader for i in b.id)
@@ -367,16 +388,17 @@ class TestExactParity:
         assert registry.counter_value(
             'petastorm_tpu_late_materialized_rows_total') == 2
 
-    def test_row_drop_partition_parity(self, synthetic_dataset):
+    @pytest.mark.parametrize('pool', ['thread', 'process'])
+    def test_row_drop_partition_parity(self, synthetic_dataset, pool):
         # shuffle_row_drop_partitions under a predicate: each row-group
         # becomes k items; the late path decides survivors + drop BEFORE
         # the heavy read (an empty partition reads nothing), and the
-        # delivered multiset must still match the oracle exactly
+        # delivered multiset must still match the plain read exactly
         pred = FiltersPredicate([('id', 'in', (3, 31, 47))])
-        kwargs = dict(predicate=pred, shuffle_row_drop_partitions=3)
-        got = _read_ids(synthetic_dataset.url, **kwargs)
-        assert got == _read_ids(synthetic_dataset.url, oracle=True,
-                                **kwargs)
+        got = _read_ids(synthetic_dataset.url, pool=pool, predicate=pred,
+                        shuffle_row_drop_partitions=3)
+        assert got == _read_ids(synthetic_dataset.url, plain=True,
+                                predicate=pred)
         assert got == [3, 31, 47]
 
     def test_fully_pruned_reader_delivers_empty(self, synthetic_dataset):
@@ -411,41 +433,45 @@ class TestCheckpointAccounting:
         assert got == list(range(25))
         assert state['epoch'] == 1 and state['consumed_items'] == []
 
-    @pytest.mark.parametrize('save_oracle,restore_oracle',
-                             [(False, True), (True, False)])
-    def test_resume_across_pushdown_knob_flip(self, synthetic_dataset,
-                                              monkeypatch, save_oracle,
-                                              restore_oracle):
+    @pytest.mark.parametrize('saver', ['fewer-items', 'more-items'])
+    def test_resume_with_differing_saved_items(self, synthetic_dataset,
+                                                saver):
         # REGRESSION (review finding): the filters= path prunes
-        # PRE-shard, so flipping PETASTORM_TPU_PUSHDOWN across a resume
-        # changes the item-index space — raw consumed indices would name
-        # DIFFERENT row-groups. _localize_state translates through the
-        # saved per-index global identities instead; no silent row loss
-        # in either flip direction.
+        # PRE-shard, so a saver whose item list differs from the
+        # restorer's (other filters; rewritten files whose statistics
+        # changed) names DIFFERENT row-groups by the same raw consumed
+        # indices. _localize_state translates through the saved per-index
+        # global identities instead; no silent row loss whether the
+        # saver had fewer items than the restorer or more.
         # an OR filter keeping a NON-contiguous piece set: the pruned
         # space's index->piece mapping then genuinely disagrees with the
         # unpruned one (a prefix-keeping filter would map identically
         # and hide the bug)
         filters = [[('id', '<', 10)], [('id', '>=', 30)]]
-        expected = set(range(10)) | set(range(30, 100))
+        pruned = set(range(10)) | set(range(30, 100))
+        save_filters, restore_filters, expected = {
+            'fewer-items': (filters, None, set(range(100))),
+            'more-items': (None, filters, pruned)}[saver]
 
-        def build(oracle):
-            if oracle:
-                monkeypatch.setenv('PETASTORM_TPU_PUSHDOWN', '0')
-            else:
-                monkeypatch.delenv('PETASTORM_TPU_PUSHDOWN', raising=False)
+        def build(filters):
             return make_batch_reader(synthetic_dataset.url, num_epochs=1,
                                      shuffle_row_groups=False,
                                      filters=filters)
-        with build(save_oracle) as reader:
+        with build(save_filters) as reader:
             it = iter(reader)
             seen = set(int(i) for i in next(it).id)
             seen |= set(int(i) for i in next(it).id)
             state = reader.state_dict()
-        with build(restore_oracle) as reader:
+        with build(restore_filters) as reader:
+            saved_items = len(state['items_global'])
+            own_items = len(reader._items_identity)
+            assert (saved_items < own_items) == (saver == 'fewer-items')
+            assert saved_items != own_items
             reader.load_state_dict(state)
             rest = set(int(i) for b in reader for i in b.id)
-        assert seen | rest == expected, sorted(expected - (seen | rest))
+        assert expected <= seen | rest, sorted(expected - (seen | rest))
+        # the consumed row-groups the restorer also holds were skipped
+        assert not (rest & seen), sorted(rest & seen)
 
     def test_mid_epoch_resume_loses_no_rows(self, synthetic_dataset):
         pred = FiltersPredicate([('id', '<', 25)])
@@ -627,13 +653,13 @@ class TestLateMaterialization:
         encoded = [c['image_png'] for c in batches]
         assert all(isinstance(e, EncodedImageColumn) for e in encoded)
         assert sorted(len(e) for e in encoded) == [1, 2]
-        # decoded survivors equal the oracle's pixels
-        oracle = {row[0]: row[1] for row in _read_rows(
-            synthetic_dataset.url, oracle=True, predicate=pred)}
+        # decoded survivors equal the plain read's pixels
+        plain = {row[0]: row[1] for row in _read_rows(
+            synthetic_dataset.url, plain=True, predicate=pred)}
         for columns in batches:
             pixels = columns['image_png'].materialize()
             for k, rid in enumerate(int(i) for i in columns['id']):
-                assert pixels[k].tobytes() == oracle[rid]
+                assert pixels[k].tobytes() == plain[rid]
 
 
 # ---------------------------------------------------------------------------

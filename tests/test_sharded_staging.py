@@ -1,13 +1,13 @@
 """Shard-aware staging engine + autotuner (ISSUE 14).
 
 Exact-parity suite on the forced-8-device CPU mesh (conftest sets
-``xla_force_host_platform_device_count=8``): staged-vs-legacy batch
-equality on a 4x2 mesh across last-batch policies, multi-epoch replays
-and a mid-stream checkpoint resume; the one-dispatch-per-pytree
-contract; the structural zero-per-batch-host-allocation guard on the
-sharded ring; the sharded row plan's soundness properties; the legacy
-path's telemetry (spans + shard-slice bytes); and the staging
-autotuner's policy, bounds, decision records and knob discipline.
+``xla_force_host_platform_device_count=8``): staged batches equal the
+plain numpy reference (``petastorm_tpu/test_util/reference.py``) on a 4x2
+mesh across last-batch policies, multi-epoch replays and fused decode; a
+mid-stream checkpoint resume; the one-dispatch-per-pytree contract; the
+structural zero-per-batch-host-allocation guard on the sharded ring; the
+sharded row plan's soundness properties; and the staging autotuner's
+policy, bounds, decision records and knob discipline.
 """
 
 import contextlib
@@ -28,6 +28,7 @@ from petastorm_tpu.parallel.mesh import DATA_AXIS, make_mesh
 from petastorm_tpu.parallel.sharding import local_shard_plan
 from petastorm_tpu.telemetry.registry import metric_key
 from petastorm_tpu.telemetry.spans import STAGE_SECONDS
+from petastorm_tpu.test_util import reference
 from petastorm_tpu.test_util.parity import assert_same_rows_by_id
 
 N_SHARDS = 4
@@ -86,9 +87,17 @@ def _read_all(url, mesh, batch_size, last_batch='drop', num_epochs=1,
     return out
 
 
-def _assert_batches_equal(staged, legacy, dropped_tail=False):
+def _expected(url, batch_size, last_batch='drop', num_epochs=1):
+    """The plain reference for a ``_read_all`` read."""
+    fields = ['id', 'float64']
+    return reference.loader_batches(
+        reference.read_rows(url, columns=fields), batch_size,
+        fields=fields, last_batch=last_batch, num_epochs=num_epochs)
+
+
+def _assert_batches_equal(staged, expected, dropped_tail=False):
     # reader pools deliver row-groups in completion order: compare by id
-    assert_same_rows_by_id(staged, legacy, dropped_tail=dropped_tail)
+    assert_same_rows_by_id(staged, expected, dropped_tail=dropped_tail)
 
 
 # -- the sharded row plan -----------------------------------------------------
@@ -127,42 +136,35 @@ def test_local_shard_plan_declines_uneven_rows(mesh):
         assert covered == set(range(10))
 
 
-# -- exact parity: staged vs PETASTORM_TPU_STAGING=0 --------------------------
+# -- exact parity: staged vs the plain reference ------------------------------
 
 
 def test_sharded_parity_drop_multi_epoch(scalar_dataset, mesh):
     staged = _read_all(scalar_dataset.url, mesh, 8, num_epochs=2)
-    with _env(PETASTORM_TPU_STAGING='0',
-              PETASTORM_TPU_STAGING_AUTOTUNE='0'):
-        legacy = _read_all(scalar_dataset.url, mesh, 8, num_epochs=2)
     # num_epochs=2 streams 200 rows through ONE staging pass: 25 full
     # batches, nothing dropped
     assert len(staged) == 200 // 8
-    _assert_batches_equal(staged, legacy)
+    _assert_batches_equal(staged, _expected(scalar_dataset.url, 8,
+                                            num_epochs=2))
 
 
 def test_sharded_parity_pad_tail_mask(scalar_dataset, mesh):
     # 100 rows, batch 24: tail of 4 zero-pads with a valid_mask
     staged = _read_all(scalar_dataset.url, mesh, 24, last_batch='pad')
-    with _env(PETASTORM_TPU_STAGING='0',
-              PETASTORM_TPU_STAGING_AUTOTUNE='0'):
-        legacy = _read_all(scalar_dataset.url, mesh, 24, last_batch='pad')
     assert all('valid_mask' in b for b in staged)
     tail = staged[-1]
     assert tail['valid_mask'].sum() == 100 % 24
     assert tail['valid_mask'].dtype == bool
-    _assert_batches_equal(staged, legacy)
+    _assert_batches_equal(staged, _expected(scalar_dataset.url, 24,
+                                            last_batch='pad'))
 
 
 def test_sharded_parity_short_tail(scalar_dataset, mesh):
     # the 4-row short tail still divides over the 4 data shards
     staged = _read_all(scalar_dataset.url, mesh, 24, last_batch='short')
-    with _env(PETASTORM_TPU_STAGING='0',
-              PETASTORM_TPU_STAGING_AUTOTUNE='0'):
-        legacy = _read_all(scalar_dataset.url, mesh, 24,
-                           last_batch='short')
     assert staged[-1]['id'].shape[0] == 100 % 24
-    _assert_batches_equal(staged, legacy)
+    _assert_batches_equal(staged, _expected(scalar_dataset.url, 24,
+                                            last_batch='short'))
 
 
 def test_sharded_checkpoint_resume_midstream(scalar_dataset, mesh):
@@ -202,7 +204,7 @@ def test_sharded_checkpoint_resume_midstream(scalar_dataset, mesh):
 def test_sharded_fused_decode_parity(synthetic_dataset, mesh):
     """Deferred image cells decode straight into the shard-slice staging
     buffers (``decode_fused``) and the sharded dispatch ships the result
-    — values exactly equal to the fully-materialized legacy path."""
+    — values exactly equal to the rows written (png is lossless)."""
     kw = dict(batch_size=8, mesh=mesh, data_axes=(DATA_AXIS,),
               num_epochs=1, fields=['^id$', '^image_png$'],
               shuffle_row_groups=False)
@@ -211,13 +213,10 @@ def test_sharded_fused_decode_parity(synthetic_dataset, mesh):
                   for b in loader]
         fused_rows = loader.diagnostics['fused_decode_rows']
         mode = loader.diagnostics['fused_decode_mode']
-    with _env(PETASTORM_TPU_STAGING='0',
-              PETASTORM_TPU_STAGING_AUTOTUNE='0'):
-        with make_jax_loader(synthetic_dataset.url, **kw) as loader:
-            legacy = [{k: np.asarray(v) for k, v in b.items()}
-                      for b in loader]
+    expected = reference.loader_batches(synthetic_dataset.data, 8,
+                                        fields=['id', 'image_png'])
     # 100 rows in batches of 8: 'drop' loses an arrival-dependent tail
-    _assert_batches_equal(staged, legacy, dropped_tail=True)
+    _assert_batches_equal(staged, expected, dropped_tail=True)
     # the fused pass really ran (CPU mesh: host-backed fresh assembly)
     assert fused_rows > 0
     assert mode == 'fused-into-slab'
@@ -360,45 +359,6 @@ def test_sharded_ring_zero_per_batch_host_allocations():
     assert eng.slabs_allocated == slabs_after_warmup == 2
     assert grown < batch_bytes / 2, \
         'staging.py allocated %d bytes over %d sharded batches' % (grown, n)
-
-
-# -- legacy-path telemetry (satellite: sharded dispatch visible) --------------
-
-
-def test_legacy_sharded_dispatch_records_span_and_bytes(mesh):
-    """PETASTORM_TPU_STAGING=0 on a mesh: the
-    make_array_from_process_local_data path still lands ``h2d_dispatch``
-    spans and counts shard-slice ``petastorm_tpu_h2d_bytes_total``.
-    Float32/int32 fields keep host dtype == device dtype, so the
-    expected byte count is exact (jax's 32-bit mode would downcast
-    64-bit fields AFTER the counted host-side bytes)."""
-
-    def factory(url, **kw):
-        return DummyBatchReader(
-            fields={'x': ((4,), np.float32), 'y': ((), np.int32)},
-            batch_size=8, num_batches=5)
-
-    with _env(PETASTORM_TPU_STAGING='0',
-              PETASTORM_TPU_STAGING_AUTOTUNE='0'):
-        registry = T.get_registry()
-        span_key = metric_key(STAGE_SECONDS, {'stage': 'h2d_dispatch'})
-        bytes_before = registry.counter_value(staging.H2D_BYTES)
-        span_before = registry.counters_with_prefix(STAGE_SECONDS).get(
-            span_key, 0.0)
-        with make_jax_loader('dummy://', batch_size=8, mesh=mesh,
-                             data_axes=(DATA_AXIS,),
-                             reader_factory=factory) as loader:
-            assert loader._stager is None   # the legacy path under test
-            batches = [{k: np.asarray(v) for k, v in b.items()}
-                       for b in loader]
-        assert len(batches) == 5
-        counted = registry.counter_value(staging.H2D_BYTES) - bytes_before
-        # shard-slice bytes: exactly the HOST-side bytes of the batches
-        expected = sum(sum(a.nbytes for a in b.values())
-                       for b in batches)
-        assert counted == expected
-        assert registry.counters_with_prefix(STAGE_SECONDS).get(
-            span_key, 0.0) > span_before
 
 
 # -- the staging autotuner ----------------------------------------------------

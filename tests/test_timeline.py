@@ -211,8 +211,8 @@ def test_armed_timeline_records_every_span_on_one_clock_with_batch_numbers(
     # the stager's spans carry the batch they work for
     staged = [e for e in events if e['tid'] == 'petastorm-tpu-stager']
     assert staged and all('batch' in e['args'] for e in staged)
-    assert {e['name'] for e in staged} & {'h2d_dispatch', 'h2d',
-                                          'stage_fill', 'collate'}
+    assert {e['name'] for e in staged} & {'h2d_dispatch', 'stage_fill',
+                                          'collate'}
 
 
 def test_disarmed_timeline_records_nothing_and_leaves_no_hook():

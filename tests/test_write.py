@@ -38,6 +38,7 @@ from petastorm_tpu.etl.dataset_metadata import (
 from petastorm_tpu.filters import FiltersPredicate
 from petastorm_tpu.fs import get_filesystem_and_path_or_paths
 from petastorm_tpu.reader import make_batch_reader
+from petastorm_tpu.test_util import reference
 from petastorm_tpu.unischema import Unischema, UnischemaField
 from petastorm_tpu.workers.thread_pool import ThreadPool
 from petastorm_tpu.write import (
@@ -738,20 +739,16 @@ class TestWriteReadContract:
         assert hits / (hits + misses) > 0.8
 
     def test_full_multiset_parity_against_oracle(self, tmp_path):
-        """Both planes off (the oracle) vs both on: identical rows."""
+        """Pruned + late-materialized + readahead read vs a plain pyarrow
+        read with pyarrow's own filters: identical rows."""
         url = 'file://' + str(tmp_path)
         write_dataset_distributed(url, SCHEMA, _rows(200), sort_by='id',
                                   shard_rows=40)
-        pred = FiltersPredicate([('id', 'in', (3, 77, 150, 199))])
-        saved = dict(os.environ)
-        os.environ['PETASTORM_TPU_PUSHDOWN'] = '0'
-        os.environ['PETASTORM_TPU_READAHEAD'] = '0'
-        try:
-            oracle = _read_ids(url, predicate=pred)
-        finally:
-            os.environ.clear()
-            os.environ.update(saved)
-        assert _read_ids(url, predicate=pred) == oracle == [3, 77, 150, 199]
+        filters = [('id', 'in', (3, 77, 150, 199))]
+        plain = sorted(row['id'] for row in reference.read_rows(
+            url, columns=['id'], filters=filters))
+        got = _read_ids(url, predicate=FiltersPredicate(filters))
+        assert got == plain == [3, 77, 150, 199]
 
 
 # ---------------------------------------------------------------------------
